@@ -381,17 +381,16 @@ def bench_turbo(smoke: bool, reps: int) -> list[dict]:
                              (t_sw - t_seed) / max(t_tb - t_seed, 1e-9),
                              2)))
 
-        if core.HAS_DISTRIBUTED:
-            mesh = jax.make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",))
 
-            def sharded(A):
-                return core.vat_matrix_free_sharded(A, mesh).order
+        def sharded(A):
+            return core.vat_matrix_free_sharded(A, mesh).order
 
-            t_sh = _time(sharded, X, reps=reps)
-            rows.append(_row("turbo", f"{tag}/sharded_1dev", t_sh,
-                             peak_bytes=_peak_bytes(sharded, X),
-                             devices=len(jax.devices()),
-                             speedup_vs_stepwise=round(t_sw / t_sh, 2)))
+        t_sh = _time(sharded, X, reps=reps)
+        rows.append(_row("turbo", f"{tag}/sharded_1dev", t_sh,
+                         peak_bytes=_peak_bytes(sharded, X),
+                         devices=len(jax.devices()),
+                         speedup_vs_stepwise=round(t_sw / t_sh, 2)))
     return rows
 
 
@@ -828,6 +827,8 @@ def main(argv=None) -> int:
     p.add_argument("--out", default=None,
                    help="output path (default BENCH_<stamp>.json in cwd)")
     a = p.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     tables = tuple(t.strip() for t in a.tables.split(",") if t.strip())
     if unknown := set(tables) - set(TABLES):

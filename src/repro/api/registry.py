@@ -424,7 +424,7 @@ def _flash_order(Xj, meta: ResultMeta, opts: RungOptions):
     cancellation-free tiles.
     """
     devs = jax.devices()
-    if (opts.turbo is None and core.HAS_DISTRIBUTED and len(devs) > 1
+    if (opts.turbo is None and len(devs) > 1
             and meta.n >= FLASH_SHARD_MIN_N and opts.num_form == "gram"):
         from jax.sharding import Mesh
         mesh = Mesh(np.array(devs), ("data",))
@@ -559,11 +559,6 @@ def _fit_embed(data, meta: ResultMeta, opts: RungOptions) -> TendencyResult:
 
 
 def _check_dvat(n: int):
-    if not core.HAS_DISTRIBUTED:
-        raise RuntimeError(
-            "method='dvat' needs a JAX with shard_map "
-            "(repro.core.HAS_DISTRIBUTED is False; cause: "
-            f"{core.DISTRIBUTED_IMPORT_ERROR})")
     devs = jax.devices()
     if len(devs) < 2:
         raise RuntimeError(

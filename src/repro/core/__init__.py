@@ -13,16 +13,8 @@ from repro.core.ivat import (ivat, ivat_batch, ivat_batch_from_dist,
                              ivat_batch_from_vat, ivat_from_vat)
 from repro.core.svat import svat, maximin_sample, SVATResult
 from repro.core.hopkins import hopkins
-try:  # optional: needs a JAX with shard_map (any home); see distributed.py
-    from repro.core.distributed import (dvat, pairwise_dist_sharded,
-                                        vat_matrix_free_sharded, DVATResult)
-    HAS_DISTRIBUTED = True
-    DISTRIBUTED_IMPORT_ERROR = None
-except ImportError as _e:  # degrade gracefully — single-host paths stay usable
-    dvat = pairwise_dist_sharded = DVATResult = None  # type: ignore[assignment]
-    vat_matrix_free_sharded = None  # type: ignore[assignment]
-    HAS_DISTRIBUTED = False
-    DISTRIBUTED_IMPORT_ERROR = repr(_e)   # keep the real cause debuggable
+from repro.core.distributed import (dvat, pairwise_dist_sharded,
+                                    vat_matrix_free_sharded, DVATResult)
 from repro.core.bigvat import bigvat, BigVATResult, nearest_prototype_assign
 from repro.core.approx_mst import (approx_vat, boruvka_mst, knn_graph_anchored,
                                    mst_vat_order, ApproxStats,
@@ -47,16 +39,14 @@ __all__ = [
     "VATResult", "FlashVATResult",
     "block_structure_score", "ivat", "ivat_batch", "ivat_batch_from_dist",
     "ivat_batch_from_vat", "ivat_from_vat", "svat",
-    "maximin_sample", "SVATResult", "hopkins", "HAS_DISTRIBUTED",
+    "maximin_sample", "SVATResult", "hopkins",
+    "dvat", "pairwise_dist_sharded", "vat_matrix_free_sharded", "DVATResult",
     "bigvat", "BigVATResult", "nearest_prototype_assign",
     "approx_vat", "boruvka_mst", "knn_graph_anchored", "mst_vat_order",
     "ApproxStats", "ApproxVATResult", "MSTEdges",
     "activation_report",
     "embedding_tendency", "router_tendency", "TendencyReport",
 ]
-if HAS_DISTRIBUTED:
-    __all__ += ["dvat", "pairwise_dist_sharded", "vat_matrix_free_sharded",
-                "DVATResult"]
 from repro.core.streaming import StreamingVAT
 __all__.append("StreamingVAT")
 from repro.core.tsne import tsne

@@ -23,16 +23,13 @@ dissimilarity matrix ... a bottleneck for n > 10^4".  Two remedies here:
   for bit on any shard count, so the flashvat rung auto-shards when more
   than one device is visible without changing a single answer.
 
-Both run under jit+shard_map on any mesh axis name (default "data").
-
-This module is optional: repro.core imports it behind a try/except and
-publishes ``repro.core.HAS_DISTRIBUTED`` (docs/scaling.md has the full
-vat -> svat -> bigvat -> dvat -> streaming ladder).
+Both run under jit + ``jax.shard_map`` on any mesh axis name (default
+"data"); docs/scaling.md has the full vat -> svat -> bigvat -> dvat ->
+streaming ladder.
 """
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import NamedTuple
 
 import jax
@@ -40,27 +37,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # jax 0.4.x / 0.5.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.ref import row_dissim_ref
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs, check: bool | None = None):
-    """Version-tolerant shard_map: the replication-check kwarg was renamed
-    from ``check_rep`` (<= 0.5) to ``check_vma`` (>= 0.6)."""
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if check is not None:
-        params = inspect.signature(_shard_map_impl).parameters
-        for name in ("check_vma", "check_rep"):
-            if name in params:
-                kwargs[name] = check
-                break
-    return _shard_map_impl(f, **kwargs)
 
 
 class DVATResult(NamedTuple):
@@ -73,7 +52,7 @@ def pairwise_dist_sharded(X: jax.Array, mesh: Mesh, axis: str = "data"):
     def shard_fn(Xl, Xfull):
         return kops.pairwise_dist(Xl, Xfull)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(axis, None), P(None, None)),
         out_specs=P(axis, None))
@@ -262,13 +241,13 @@ def _flash_sharded_program(mesh: Mesh, axis: str, n: int, metric: str,
     compile-once-run-many behavior the solo engines get from their
     module-level ``@jax.jit``.
     """
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_flash_shard, axis=axis, n=n, metric=metric,
                           use_pallas=use_pallas, block=block),
         mesh=mesh,
         in_specs=(P(axis, None),),
         out_specs=(P(), P()),             # order/edges replicated
-        check=False)
+        check_vma=False)
     return jax.jit(fn)
 
 
@@ -333,11 +312,11 @@ def dvat(X: jax.Array, mesh: Mesh, axis: str = "data", *,
     dissimilarity (one of ``kernels.ref.METRICS``) — every distance row
     is recomputed from points, so any rowwise-computable metric works.
     """
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_dvat_shard, axis=axis, exact_start=exact_start,
                           metric=metric),
         mesh=mesh,
         in_specs=(P(axis, None),),
         out_specs=P(),  # order replicated (built from all_gathered data)
-        check=False)
+        check_vma=False)
     return DVATResult(order=jax.jit(fn)(X))

@@ -337,8 +337,12 @@ def vat_matrix_free_batch(X: jax.Array, *, metric: str = "euclidean",
     """Batched Flash-VAT: exact matrix-free orderings for a (b, n, d) stack.
 
     One compiled program serves all b datasets.  ``turbo=True`` (default)
-    vmaps the persistent single-scan mirror (the megakernel itself is
-    solo-only — its DMA streaming does not batch); ``turbo=False`` keeps
+    vmaps the persistent single-scan mirror on the XLA path; on the
+    Pallas path the megakernel is solo-only (its DMA streaming does not
+    batch), so the lanes run the solo engine one after another
+    (``lax.map``) — the same kernel as a solo fit, hence the same order
+    on a TPU, where the megakernel's and the mirror's dots round
+    differently and near-ties can break apart; ``turbo=False`` keeps
     the stepwise engines — the XLA path vmaps the solo engine, the
     Pallas path drives the batched fused kernel (slab-of-1 grid,
     ``kernels.prim_stream.prim_stream_step_pallas_batch``) so
@@ -355,15 +359,17 @@ def vat_matrix_free_batch(X: jax.Array, *, metric: str = "euclidean",
       FlashVATResult with a leading batch axis: order (b, n) int32,
       edges (b, n) float32.
     """
+    if turbo and use_pallas:
+        return lax.map(functools.partial(
+            vat_matrix_free, metric=metric, form=form, block=block,
+            use_pallas=True), X)
     if turbo:
         Xf = X.astype(jnp.float32)
         aux = kref.metric_aux_ref(Xf, metric=metric)
         i0 = jax.vmap(functools.partial(
-            _streamed_seed_pivot, metric=metric, form=form,
-            use_pallas=use_pallas))(Xf)
+            _streamed_seed_pivot, metric=metric, form=form))(Xf)
         order, edges = kops.prim_persist(Xf, aux, i0, metric=metric,
-                                         form=form, block=block,
-                                         use_pallas=use_pallas)
+                                         form=form, block=block)
         return FlashVATResult(order=order, edges=edges)
     if not use_pallas:
         return jax.vmap(functools.partial(

@@ -16,10 +16,12 @@ written per step:
   * each step is three VPU-friendly (1, n) vector ops (masked argmin,
     max-merge, predicated select) plus two O(n) stores — no
     full-matrix traffic.
-  * the column store ``o_ref[0, :, ds(r, 1)]`` is a dynamic lane-dim
-    scatter; Mosaic lowers it as a strided store (docs/kernels.md
-    discusses the cost and the VMEM ceiling this kernel accepts to keep
-    D' resident).
+  * the column store is a read-modify-write of the aligned 128-lane
+    column chunk holding column r: the TPU compiler refuses a store at
+    an unaligned dynamic lane (``o_ref[0, :, ds(r, 1)]``).  The new
+    column is built in column layout from D'[:, j] — read through j's
+    aligned chunk, which equals row j because D' is kept exactly
+    symmetric — so no row-to-column relayout is needed.
 
 VMEM budget: input slab + output slab = 2 * n^2 * 4 B, so n <= 1024 fits
 comfortably (~8.4 MiB with temporaries) and n = 1448 is the hard ceiling
@@ -39,6 +41,10 @@ _LANE = 128   # pad n to a lane multiple so (1, n) rows are VREG-aligned
 MAX_FUSED_N = 1024  # keep 2 * n^2 * 4B well under the 16 MiB VMEM core
 
 
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
 def _ivat_kernel(rstar_ref, o_ref):
     """One recurrence step r = program_id(1) + 1 on a (1, n, n) slab pair."""
     n = rstar_ref.shape[-1]
@@ -49,16 +55,22 @@ def _ivat_kernel(rstar_ref, o_ref):
     def _init():  # D'[0, :] = D'[:, 0] = 0 seeds the recurrence
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    row = rstar_ref[0, pl.ds(r, 1), :].reshape(n).astype(jnp.float32)
-    k = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-    prefix = k < r                                  # already-ordered points
-    masked = jnp.where(prefix, row, jnp.inf)
-    j = jnp.argmin(masked).astype(jnp.int32)        # nearest ordered point
+    row = rstar_ref[0, pl.ds(r, 1), :].astype(jnp.float32)    # (1, n)
+    masked = jnp.where(_iota((1, n), 1) < r, row, jnp.inf)  # ordered prefix
     dcut = jnp.min(masked)                          # = R*[r, j], the MST edge
-    dpj = o_ref[0, pl.ds(j, 1), :].reshape(n)       # D'[j, :]
-    newrow = jnp.where(prefix, jnp.maximum(dcut, dpj), 0.0)
-    o_ref[0, pl.ds(r, 1), :] = newrow.reshape(1, n).astype(o_ref.dtype)
-    o_ref[0, :, pl.ds(r, 1)] = newrow.reshape(n, 1).astype(o_ref.dtype)
+    j = jnp.min(jnp.where(masked == dcut, _iota((1, n), 1), n))  # first argmin
+    dpj = o_ref[0, pl.ds(j, 1), :]                  # D'[j, :], (1, n)
+    newrow = jnp.where(_iota((1, n), 1) < r, jnp.maximum(dcut, dpj), 0.0)
+    o_ref[0, pl.ds(r, 1), :] = newrow
+    # column r = row r, built in column layout: D'[:, j] via j's chunk
+    lane = _iota((n, _LANE), 1)
+    cj = pl.multiple_of((j // _LANE) * _LANE, _LANE)
+    dpj_col = jnp.sum(jnp.where(lane == j - cj, o_ref[0, :, pl.ds(cj, _LANE)],
+                                0.0), axis=1, keepdims=True)      # (n, 1)
+    newcol = jnp.where(_iota((n, 1), 0) < r, jnp.maximum(dcut, dpj_col), 0.0)
+    cr = pl.multiple_of((r // _LANE) * _LANE, _LANE)
+    o_ref[0, :, pl.ds(cr, _LANE)] = jnp.where(
+        lane == r - cr, newcol, o_ref[0, :, pl.ds(cr, _LANE)])
 
 
 def _pad_square(R: jax.Array, n_pad: int) -> jax.Array:

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the kernels package.
 
-Dispatch policy: Pallas kernels target TPU; on a CPU backend (this
-container) they run in ``interpret=True`` mode for correctness validation,
-while the default production path on CPU is the XLA reference in ref.py.
+Dispatch policy: Pallas kernels target TPU, where they compile through
+Mosaic; on the CPU backend they run in ``interpret=True`` mode for
+correctness validation, while the default production path on CPU is the
+XLA reference in ref.py.
 """
 from __future__ import annotations
 
@@ -28,6 +29,8 @@ from repro.kernels.prim_update import masked_argmin_pallas
 
 
 def _interpret() -> bool:
+    """Interpret mode on the CPU backend only; every other backend
+    compiles the kernel (a refusal surfaces, it is never interpreted)."""
     return jax.default_backend() == "cpu"
 
 
@@ -243,7 +246,8 @@ def prim_persist(X: jax.Array, aux: jax.Array, i0: jax.Array, *,
     mirror — the stepwise engine is never silently substituted (pinned
     by tests/test_turbo.py).  Batched (b, n, d) input vmaps the mirror:
     the megakernel is deliberately solo-only (its DMA streaming does not
-    batch; per-lane orderings are identical either way).
+    batch), so ``core.vat_matrix_free_batch`` maps the solo engine over
+    the lanes on the Pallas path instead of calling this batched.
 
     Args:
       X: (n, d) or (b, n, d) float — data points (unpadded).
@@ -257,7 +261,12 @@ def prim_persist(X: jax.Array, aux: jax.Array, i0: jax.Array, *,
 
     Returns:
       (order, edges) with the input's leading shape — (n,)/(b, n) i32
-      and f32; bitwise-identical across every path for every metric.
+      and f32.  Every path runs the same exact Prim recurrence, so the
+      spanning-tree weight (the edge sum) agrees across paths to f32
+      rounding.  The orders agree except at near-ties, which the
+      megakernel's and the mirror's dot lowerings may break differently
+      (on a TPU v5e at n = 50 000 the two orders part in about 2.5% of
+      positions; on the CPU the pinned cases are bitwise-equal).
     """
     _dispatch_site("prim_persist", use_pallas)
     if X.ndim == 3:

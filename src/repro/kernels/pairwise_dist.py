@@ -22,8 +22,11 @@ compiles its own kernel):
 
 VMEM budget at the default BM=BN=256, d<=512 (matmul metrics):
   2 * 256*512*4B (tiles) + 256*256*4B (out) ~= 1.3 MiB  << 16 MiB VMEM.
-Manhattan's broadcast chunk adds BM*BN*128*4B, so its block is clamped
-to 64: 64*64*128*4B = 2 MiB — still comfortable.
+Manhattan's broadcast chunk adds BM*BN*128*4B, so its tile is
+(32, 128): 32*128*128*4B = 2 MiB.  The column edge stays 128 because
+the TPU compiler takes an output block only if its last dimension is a
+multiple of 128 lanes (or the whole array); ``_tile_shape`` owns that
+rule for both kernels here.
 
 Zero padding is harmless for every metric, for two different reasons:
 padded *features* contribute the reduction identity (0) to dots, squared
@@ -43,12 +46,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import ref
 from repro.kernels.ref import check_metric
 from repro.numerics.condition import check_form
 
 DEFAULT_BLOCK = 256
 _LANE = 128  # MXU/VREG lane width — pad contraction dim to a multiple
 _MANHATTAN_BLOCK = 64  # broadcast-chunk metrics pay BM*BN*_LANE VMEM
+_BCAST_ROWS = 32  # the pairwise broadcast-chunk tile is (_BCAST_ROWS, _LANE)
 
 
 def _tile_dissim(x, y, metric, form):
@@ -58,7 +63,7 @@ def _tile_dissim(x, y, metric, form):
     numerics policies) trades the single MXU matmul for the manhattan
     -style broadcast-chunk loop over squared differences — no
     cancellation, ~2x slower, and it pays the same BM*BN*_LANE VMEM
-    bill (so ``_clamp_block`` clamps it like manhattan).
+    bill (so ``_tile_shape`` sizes it like manhattan).
     """
     if metric == "manhattan":
         acc = jnp.zeros((x.shape[0], y.shape[0]), jnp.float32)
@@ -74,7 +79,7 @@ def _tile_dissim(x, y, metric, form):
             acc += jnp.sum(dc * dc, axis=-1)
         return jnp.sqrt(acc) if metric == "euclidean" else acc
     cross = jax.lax.dot_general(                # MXU: (BM, d) x (BN, d)^T
-        x, y, (((1,), (1,)), ((), ())),
+        x, y, (((1,), (1,)), ((), ())), precision=ref.GRAM_PRECISION,
         preferred_element_type=jnp.float32)
     if metric == "cosine":
         nx = jnp.sqrt(jnp.sum(x * x, axis=1))   # (BM,)
@@ -108,11 +113,33 @@ def _pad_to(a: jax.Array, size: int, axis: int) -> jax.Array:
     return jnp.pad(a, widths)
 
 
+def _broadcast_tile(metric: str, form: str) -> bool:
+    return metric == "manhattan" or (form == "direct" and metric != "cosine")
+
+
 def _clamp_block(block: int, n: int, metric: str,
                  form: str = "gram") -> int:
-    if metric == "manhattan" or (form == "direct" and metric != "cosine"):
+    """Square tile edge of the kNN fold (whose (BM, k) output blocks
+    take any multiple of 8 rows): ``block`` clamped to n, and to
+    ``_MANHATTAN_BLOCK`` for the broadcast-chunk tiles."""
+    if _broadcast_tile(metric, form):
         block = min(block, _MANHATTAN_BLOCK)  # broadcast-chunk VMEM bill
     return min(block, max(8, n))
+
+
+def _tile_shape(block: int, n: int, m: int, metric: str,
+                form: str) -> tuple[int, int]:
+    """(BM, BN) output tile of an (n, m) dissimilarity matrix.
+
+    BN is the lane edge: 128 for the broadcast-chunk tiles, ``block``
+    for the matmul tiles, clamped to m — so it is a multiple of 128 or
+    the whole m, the two shapes the TPU compiler accepts.
+    """
+    if _broadcast_tile(metric, form):
+        bm, bn = min(block, _BCAST_ROWS), _LANE
+    else:
+        bm = bn = block
+    return min(bm, max(8, n)), min(bn, max(8, m))
 
 
 @functools.partial(jax.jit,
@@ -135,9 +162,10 @@ def pairwise_dist_pallas(
         compiles its own tile; see the module docstring for the math).
       form: "gram" (default) or "direct" — the numerics-policy tile
         form (static; see ``_tile_dissim`` and ``numerics.resolve``).
-      block: output tile edge BM = BN (static; clamped to n/m, and to
-        ``_MANHATTAN_BLOCK`` for the broadcast-chunk tiles — manhattan,
-        and direct-form euclidean/sqeuclidean).
+      block: output tile edge BM = BN (static; clamped to n/m; the
+        broadcast-chunk tiles — manhattan, and direct-form
+        euclidean/sqeuclidean — use (``_BCAST_ROWS``, 128), see
+        ``_tile_shape``).
       interpret: Pallas interpret mode (CPU correctness path).
 
     Returns:
@@ -152,8 +180,7 @@ def pairwise_dist_pallas(
         Y = X
     n, d = X.shape
     m = Y.shape[0]
-    bm = _clamp_block(block, n, metric, form)
-    bn = _clamp_block(block, m, metric, form)
+    bm, bn = _tile_shape(block, n, m, metric, form)
     n_pad = -(-n // bm) * bm
     m_pad = -(-m // bn) * bn
     d_pad = -(-d // _LANE) * _LANE
@@ -191,8 +218,8 @@ def pairwise_dist_pallas_batch(
       metric: one of ``kernels.ref.METRICS`` (static).
       form: "gram" (default) or "direct" — the numerics-policy tile
         form (static).
-      block: square output tile edge (BM = BN); clamped to n (and to
-        ``_MANHATTAN_BLOCK`` for the broadcast-chunk tiles).
+      block: output tile edge (BM = BN); clamped to n, and shaped by
+        ``_tile_shape`` for the broadcast-chunk tiles.
       interpret: Pallas interpret mode (CPU correctness path).
 
     Returns:
@@ -207,20 +234,21 @@ def pairwise_dist_pallas_batch(
     check_metric(metric)
     check_form(form)
     b, n, d = X.shape
-    bm = _clamp_block(block, n, metric, form)
-    n_pad = -(-n // bm) * bm
+    bm, bn = _tile_shape(block, n, n, metric, form)
+    n_pad = -(-n // bm) * bm        # rows and columns pad separately when
+    m_pad = -(-n // bn) * bn        # the tile is not square
     d_pad = -(-d // _LANE) * _LANE
-    Xp = _pad_to(_pad_to(X, n_pad, 1), d_pad, 2)
+    Xd = _pad_to(X, d_pad, 2)
 
     out = pl.pallas_call(
         functools.partial(_pairwise_kernel_batch, metric=metric, form=form),
-        grid=(b, n_pad // bm, n_pad // bm),
+        grid=(b, n_pad // bm, m_pad // bn),
         in_specs=[
             pl.BlockSpec((1, bm, d_pad), lambda bi, i, j: (bi, i, 0)),
-            pl.BlockSpec((1, bm, d_pad), lambda bi, i, j: (bi, j, 0)),
+            pl.BlockSpec((1, bn, d_pad), lambda bi, i, j: (bi, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bm, bm), lambda bi, i, j: (bi, i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, n_pad, n_pad), jnp.float32),
+        out_specs=pl.BlockSpec((1, bm, bn), lambda bi, i, j: (bi, i, j)),
+        out_shape=jax.ShapeDtypeStruct((b, n_pad, m_pad), jnp.float32),
         interpret=interpret,
-    )(Xp, Xp)
+    )(_pad_to(Xd, n_pad, 1), _pad_to(Xd, m_pad, 1))
     return out[:, :n, :n]

@@ -5,14 +5,13 @@ O(n^2) memory wall but kept a time wall: n-1 separate ``pallas_call``
 dispatches, each round-tripping the O(n) frontier state through HBM.
 This module is the Turbo layer — ONE persistent kernel that:
 
-  * keeps every piece of traversal state VMEM-resident for the whole
-    run: the frontier ``mind`` (selected lanes in-band as +inf), the
-    ``order``/``edges`` outputs, and the per-tile pruning state
-    (``tmin``/``pend_lb``/``nfold``).  At n = 100k the f32 state is
-    ~2 MB — far under the 16 MiB core;
+  * keeps every piece of traversal state on-core for the whole run: the
+    frontier ``mind`` (selected lanes in-band as +inf) and the per-tile
+    pruning state (``tmin``/``pend_lb``/``nfold``) in VMEM, the
+    ``order``/``edges`` outputs in SMEM;
   * streams X tiles HBM->VMEM on demand with explicit DMA (X lives in
-    ``ANY`` memory space; only one (block, d_pad) tile plus one pivot row
-    is ever resident), so VMEM stays O(n + block·d);
+    ``ANY`` memory space; only one (block, d_pad) tile plus the pivot
+    row's 8-row group is ever resident), so VMEM stays O(n + block·d);
   * prunes with per-tile frontier lower bounds (lazy Prim): a tile whose
     bound provably exceeds the best exact candidate skips its distance
     recompute — and its DMA — entirely this step.  On clustered data
@@ -46,11 +45,16 @@ Metric geometry of the bound: euclidean/manhattan are metrics, so
 space and squares; cosine is not a metric here, so its radius is +inf
 and the bound degrades to 0 — correct, just never prunes.
 
-Scalar state (loop carries, DMA indices) stays in registers/SMEM; the
-seed vertex arrives via an SMEM (1,) block.  Padded lanes (from
-``prim_stream.pad_points``) are +inf in-band from step 0 and can never
-win; padded tail columns of X are zeros, which contribute exact 0.0
-terms to every dot product.
+TPU layout.  The frontier is a lane-dense (1, n) VMEM scratch that the
+kernel reads and writes through ref slices: a tile at a block-aligned
+offset, a single lane through its aligned 128-lane chunk (the TPU
+lowers no ``dynamic_slice`` of a value and no unaligned dynamic lane
+access).  Per-tile state is an (nblk, 1) column updated by masked
+selects.  ``order`` and ``edges`` are SMEM outputs written one scalar
+per step, which caps n by ``PERSIST_SMEM_BUDGET``; the seed vertex
+arrives via an SMEM (1,) block.  Padded lanes are +inf in-band from
+step 0 and can never win; padded tail columns of X are zeros, which
+contribute exact 0.0 terms to every dot product.
 """
 from __future__ import annotations
 
@@ -62,15 +66,20 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.prim_stream import (_LANE, DEFAULT_BLOCK, _tile_pivot_row,
-                                       pad_points)
+from repro.kernels.prim_stream import _LANE, DEFAULT_BLOCK, _tile_pivot_row
 from repro.kernels.ref import UNSEEN, check_metric
 from repro.numerics.condition import check_form, lb_slack_ulps
 
-#: VMEM the persistent kernel may plan for (bytes).  Conservative slice
-#: of the ~16 MiB core: leaves room for compiler temporaries and the
-#: double-buffering headroom the DMA pipeline wants.
-PERSIST_VMEM_BUDGET = 12 * 1024 * 1024
+#: VMEM the persistent kernel may plan for (bytes): the compiler's
+#: 16 MiB scoped-VMEM limit on v5e less 1 MiB of headroom.
+#: ``persist_state_bytes`` is fitted to what compiling for a described
+#: v5e shows (see its docstring), so the guard admits only shapes that
+#: compile.
+PERSIST_VMEM_BUDGET = 15 * 1024 * 1024
+
+#: SMEM the order/edges outputs may take (bytes): the v5e core has
+#: 1 MiB of SMEM, and the compiler refuses a kernel that asks for more.
+PERSIST_SMEM_BUDGET = 960 * 1024
 
 #: Relative safety factor applied to every pruning lower bound.  The
 #: bound math (direct-form centroid distance minus radius) carries a few
@@ -92,15 +101,30 @@ _LB_MARGIN = 0.999
 #: and keep only a tiny final-sum allowance (4).
 _F32_EPS = float(jnp.finfo(jnp.float32).eps)
 
+_SUB = 8  # f32 sublane tile: rows are fetched in aligned groups of 8
+
+
+def _persist_block(n: int, block: int) -> int:
+    """Tile length of an n-point run: ``block`` rounded up to whole
+    128-lane rows, capped at n rounded up the same way.  Tiles are lane
+    slices of the (1, n) frontier at multiples of the tile length, and
+    the TPU takes a dynamic lane slice only at a 128-aligned offset."""
+    return min(-(-block // _LANE) * _LANE, -(-max(n, 1) // _LANE) * _LANE)
+
 
 def persist_state_bytes(n: int, d: int, *, block: int = DEFAULT_BLOCK) -> int:
     """VMEM bytes the persistent kernel keeps resident for an (n, d) run.
 
-    Mirrors ``prim_stream.pad_points`` padding arithmetic.  Counted:
-    the in-band frontier + aux + an iota temporary (3 f32 lanes per
-    padded point), order/edges outputs, per-tile pruning state and
-    centroids, and the X-tile + pivot-row DMA scratch.  X itself is NOT
-    counted — it stays in ANY/HBM and is streamed tile-by-tile.
+    Mirrors the wrapper's padding arithmetic, with coefficients fitted
+    to the scoped VMEM the v5e compiler allocates: 12 lane-dense f32
+    rows per padded point (the frontier scratch, aux, the iota and the
+    per-step compare/select temporaries), the X tile three times (DMA
+    scratch, loaded value, one elementwise temporary), the per-tile
+    centroids and bounds, and the pivot row group.  X itself stays in
+    HBM and is streamed tile-by-tile; order/edges live in SMEM
+    (``persist_smem_bytes``).  At n = 100 000 the compile overflows
+    16 MiB at d = 1024 (cosine) and fits at d = 512; at n = 50 000 it
+    fits at d = 1280 and overflows at d = 1536.
 
     Args:
       n: real point count.
@@ -110,25 +134,33 @@ def persist_state_bytes(n: int, d: int, *, block: int = DEFAULT_BLOCK) -> int:
     Returns:
       bytes — compare against ``PERSIST_VMEM_BUDGET``.
     """
-    bn = min(block, max(8, n))
+    bn = _persist_block(n, block)
     n_pad = -(-n // bn) * bn
+    n_lane = -(-n_pad // _LANE) * _LANE
     d_pad = -(-d // _LANE) * _LANE
     nblk = n_pad // bn
-    per_point = 3 * 4 * n_pad          # mind + aux + iota (f32/i32)
-    outputs = 2 * 4 * n                # order + edges
-    per_tile = nblk * (d_pad * 4 + 5 * 4)  # centroid row + caux/rad/tmin/pend/nfold
-    scratch = (bn * d_pad + d_pad) * 4     # X tile + pivot row
-    return per_point + outputs + per_tile + scratch
+    per_point = 12 * 4 * n_lane
+    per_tile = nblk * (d_pad * 4 + 5 * 4)  # centroid row + rad/tmin/pend/nfold
+    scratch = (3 * bn + _SUB) * d_pad * 4
+    return per_point + per_tile + scratch
+
+
+def persist_smem_bytes(n: int) -> int:
+    """SMEM bytes of an n-point run: the order and edges outputs, which
+    the kernel writes one scalar per step."""
+    return 2 * 4 * n + 64
 
 
 def persist_supported(n: int, d: int, *, block: int = DEFAULT_BLOCK) -> bool:
-    """True when the resident state fits ``PERSIST_VMEM_BUDGET``.
+    """True when the resident state fits ``PERSIST_VMEM_BUDGET`` and the
+    outputs fit ``PERSIST_SMEM_BUDGET``.
 
     The dispatch guard ``kernels.ops.prim_persist`` consults; above the
     seam the XLA mirror (``ref.prim_persist_ref``) — never the stepwise
     engine — takes over.
     """
-    return persist_state_bytes(n, d, block=block) <= PERSIST_VMEM_BUDGET
+    return (persist_state_bytes(n, d, block=block) <= PERSIST_VMEM_BUDGET
+            and persist_smem_bytes(n) <= PERSIST_SMEM_BUDGET)
 
 
 def persist_tile_bounds(Xp: jax.Array, n: int, *, metric: str,
@@ -136,7 +168,7 @@ def persist_tile_bounds(Xp: jax.Array, n: int, *, metric: str,
     """Per-tile (centroid, radius) for the pruning bounds.
 
     Args:
-      Xp: (n_pad, d_pad) f32 — points padded by ``pad_points``.
+      Xp: (n_pad, d_pad) f32 — zero-padded points.
       n: real point count (padded lanes are excluded from the geometry).
       metric: one of ``kernels.ref.METRICS``.
       block: tile length (must divide n_pad).
@@ -169,41 +201,68 @@ def persist_tile_bounds(Xp: jax.Array, n: int, *, metric: str,
     return cent, jnp.maximum(rad, 0.0)
 
 
-def _persist_kernel(i0_ref, aux_ref, cent_ref, rad_ref, x_ref,
-                    order_ref, edges_ref, stats_ref, tile_ref, row_ref,
-                    sem_t, sem_r, *, n, metric, form, block, prune):
-    n_pad = aux_ref.shape[0]
-    nblk = n_pad // block
-    aux = aux_ref[...]
-    cent = cent_ref[...]
-    rad = rad_ref[...]
-    iota = lax.broadcasted_iota(jnp.int32, (n_pad, 1), 0)[:, 0]
-    blk_iota = lax.broadcasted_iota(jnp.int32, (nblk, 1), 0)[:, 0]
+def _persist_kernel(i0_ref, aux_ref, cent_ref, rad_ref, tmin_ref, x_ref,
+                    order_ref, edges_ref, stats_ref, mind_ref, tile_ref,
+                    row_ref, sem_t, sem_r, *, n, metric, form, block, prune):
+    n_lane = aux_ref.shape[1]
+    nblk = cent_ref.shape[0]
+    cent = cent_ref[...]                                    # (nblk, d_pad)
+    rad = rad_ref[...]                                      # (nblk, 1)
+    iota = lax.broadcasted_iota(jnp.int32, (1, n_lane), 1)
+    blk_iota = lax.broadcasted_iota(jnp.int32, (nblk, 1), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (1, _LANE), 1)
     i0 = i0_ref[0]
     inf = jnp.float32(jnp.inf)
 
+    sub = lax.broadcasted_iota(jnp.int32, (_SUB, 1), 0)
+
     def fetch_row(p):
-        """DMA point p's (padded) row HBM->VMEM; returns it (1, d_pad)."""
-        cp = pltpu.make_async_copy(x_ref.at[pl.ds(p, 1)], row_ref, sem_r)
+        """Point p's (padded) row, (1, d_pad): DMA the aligned 8-row group
+        holding it (the TPU refuses a 1-row slice of a tiled buffer) and
+        select the row by a masked sum, which adds exact zeros."""
+        p8 = pl.multiple_of((p // _SUB) * _SUB, _SUB)
+        cp = pltpu.make_async_copy(x_ref.at[pl.ds(p8, _SUB)], row_ref, sem_r)
         cp.start()
         cp.wait()
-        return row_ref[...]
+        return jnp.sum(jnp.where(sub == p - p8, row_ref[...], 0.0), axis=0,
+                       keepdims=True)
+
+    def chunk_at(p):
+        """Start of the 128-lane chunk holding lane p: the TPU has no
+        load or store at an unaligned dynamic lane, so single lanes are
+        read and written through their aligned chunk."""
+        return pl.multiple_of((p // _LANE) * _LANE, _LANE)
+
+    def aux_at(p):
+        c = chunk_at(p)                 # masked sum adds exact zeros
+        return jnp.sum(jnp.where(lane == p - c, aux_ref[:, pl.ds(c, _LANE)],
+                                 0.0))
+
+    def tile_start(T):
+        return 0 if nblk == 1 else pl.multiple_of(T * block, block)
+
+    def pick(vec, T):
+        """vec[T] of a per-tile (nblk, 1) int vector."""
+        return jnp.sum(jnp.where(blk_iota == T, vec, 0))
 
     # row-side cancellation allowance, squared-distance units, per tile
     # form (the module constants explain why a relative margin alone is
     # unsound against Gram rows)
-    slack_sq = jnp.float32(lb_slack_ulps(form) * _F32_EPS) * jnp.max(aux)
+    slack_sq = (jnp.float32(lb_slack_ulps(form) * _F32_EPS)
+                * jnp.max(aux_ref[...]))
 
     def tile_lb(xq):
         """Lower bound on d(q, any lane of tile T) for every T: triangle
         inequality off the tile centroid — DIRECT-form centroid distance
         (relative error only, matching the radius computation), shrunk
-        by _LB_MARGIN and debited the Gram slack.  xq is (1, d_pad)."""
+        by _LB_MARGIN and debited the Gram slack.  xq is (1, d_pad);
+        the bound is (nblk, 1)."""
         diff = cent - xq
         if metric == "manhattan":
-            dq = jnp.sum(jnp.abs(diff), axis=-1)
+            dq = jnp.sum(jnp.abs(diff), axis=-1, keepdims=True)
         else:
-            dq = jnp.sqrt(jnp.maximum(jnp.sum(diff * diff, axis=-1), 0.0))
+            dq = jnp.sqrt(jnp.maximum(
+                jnp.sum(diff * diff, axis=-1, keepdims=True), 0.0))
         e = jnp.maximum(dq - rad, 0.0) * jnp.float32(_LB_MARGIN)
         if metric == "euclidean":
             lb = jnp.maximum(e - jnp.sqrt(slack_sq), 0.0)
@@ -216,51 +275,42 @@ def _persist_kernel(i0_ref, aux_ref, cent_ref, rad_ref, x_ref,
         return lb
 
     # frontier init: +inf = selected or padding, UNSEEN = no fold yet
-    mind0 = jnp.where((iota >= n) | (iota == i0), inf, jnp.float32(UNSEEN))
-    tmin0 = jnp.min(mind0.reshape(nblk, block), axis=1)
-    pend0 = jnp.full((nblk,), inf)
-    nfold0 = jnp.zeros((nblk,), jnp.int32)
-    order0 = jnp.where(lax.broadcasted_iota(jnp.int32, (n, 1), 0)[:, 0] == 0,
-                       i0, 0).astype(jnp.int32)
-    edges0 = jnp.zeros((n,), jnp.float32)
+    mind_ref[...] = jnp.where((iota >= n) | (iota == i0), inf,
+                              jnp.float32(UNSEEN))
+    order_ref[0] = i0
+    edges_ref[0] = jnp.float32(0.0)
 
-    def fold_tile(T, t, mind, tmin, pend, nfold, order, stats):
+    def fold_tile(T, t, tmin, pend, nfold, fetches, folds):
         """Fold every pending pivot (order[nfold[T]:t]) into tile T."""
-        start = T * block
+        start = tile_start(T)
         cp = pltpu.make_async_copy(x_ref.at[pl.ds(start, block)], tile_ref,
                                    sem_t)
         cp.start()
         cp.wait()
         tile = tile_ref[...]
-        aux_t = lax.dynamic_slice(aux, (start,), (block,))
-        mt = lax.dynamic_slice(mind, (start,), (block,))
-        k0 = lax.dynamic_slice(nfold, (T,), (1,))[0]
+        aux_t = aux_ref[:, pl.ds(start, block)]
+        k0 = pick(nfold, T)
 
         def fold_one(k, mt):
-            p = lax.dynamic_slice(order, (k,), (1,))[0]
+            p = order_ref[k]
             xp = fetch_row(p)                               # (1, d_pad)
-            ap = lax.dynamic_slice(aux, (p,), (1,))         # (1,)
             # the stream kernel's own tile formula — term-for-term (and
             # dot-shape-for-dot-shape) identical rows across both Pallas
             # engines, so near-tie metrics cannot flip between them on
             # 1-ulp dot-lowering differences
-            row = _tile_pivot_row(tile, xp, aux_t, ap, metric, form)
+            row = _tile_pivot_row(tile, xp, aux_t, aux_at(p), metric, form)
             return jnp.where(jnp.isinf(mt), inf, jnp.minimum(mt, row))
 
-        mt = lax.fori_loop(k0, t, fold_one, mt)
-        mnew = jnp.min(mt)
-        mind = lax.dynamic_update_slice(mind, mt, (start,))
-        tmin = lax.dynamic_update_slice(tmin, mnew[None], (T,))
-        # a traced +inf (a (1,) constant would be captured; mnew*0 would
-        # make NaN when the tile is fully selected and mnew is +inf)
-        pend = lax.dynamic_update_slice(pend, jnp.maximum(mnew, inf)[None],
-                                        (T,))
-        nfold = lax.dynamic_update_slice(nfold, t[None], (T,))
-        stats = stats + jnp.stack([jnp.int32(1), (t - k0).astype(jnp.int32)])
-        return mind, tmin, pend, nfold, stats
+        mt = lax.fori_loop(k0, t, fold_one, mind_ref[:, pl.ds(start, block)])
+        mind_ref[:, pl.ds(start, block)] = mt
+        hit = blk_iota == T
+        tmin = jnp.where(hit, jnp.min(mt), tmin)
+        pend = jnp.where(hit, inf, pend)
+        nfold = jnp.where(hit, t, nfold)
+        return tmin, pend, nfold, fetches + 1, folds + (t - k0)
 
     def step(t, carry):
-        mind, tmin, pend, nfold, order, edges, stats, q = carry
+        tmin, pend, nfold, fetches, folds, q = carry
         xq = fetch_row(q)                                   # (1, d_pad)
         pend = jnp.minimum(pend, tile_lb(xq))
 
@@ -276,42 +326,40 @@ def _persist_kernel(i0_ref, aux_ref, cent_ref, rad_ref, x_ref,
             return jnp.where(foldable, jnp.minimum(tmin, pend), inf)
 
         def fold_cond(s):
-            fuel, mind, tmin, pend, nfold, stats = s
+            fuel, tmin, pend, nfold, _, _ = s
             bound = fold_bound(tmin, pend, nfold)
             best_exact = jnp.min(jnp.where(nfold == t, tmin, inf))
             return (fuel < nblk) & (jnp.min(bound) <= best_exact)
 
         def fold_body(s):
-            fuel, mind, tmin, pend, nfold, stats = s
+            fuel, tmin, pend, nfold, fetches, folds = s
             bound = fold_bound(tmin, pend, nfold)
-            bmin = jnp.min(bound)
-            T = jnp.min(jnp.where(bound == bmin, blk_iota, nblk))
-            mind, tmin, pend, nfold, stats = fold_tile(
-                T, t, mind, tmin, pend, nfold, order, stats)
-            return fuel + 1, mind, tmin, pend, nfold, stats
+            T = jnp.min(jnp.where(bound == jnp.min(bound), blk_iota, nblk))
+            return (fuel + 1, *fold_tile(T, t, tmin, pend, nfold, fetches,
+                                         folds))
 
-        _, mind, tmin, pend, nfold, stats = lax.while_loop(
+        _, tmin, pend, nfold, fetches, folds = lax.while_loop(
             fold_cond, fold_body,
-            (jnp.int32(0), mind, tmin, pend, nfold, stats))
+            (jnp.int32(0), tmin, pend, nfold, fetches, folds))
 
         best = jnp.min(jnp.where(nfold == t, tmin, inf))
-        winner = jnp.min(jnp.where(mind == best, iota, n_pad)).astype(
-            jnp.int32)
-        mind = lax.dynamic_update_slice(mind, jnp.maximum(best, inf)[None],
-                                        (winner,))
+        winner = jnp.min(jnp.where(mind_ref[...] == best, iota, n_lane))
+        c = chunk_at(winner)
+        mind_ref[:, pl.ds(c, _LANE)] = jnp.where(
+            lane == winner - c, inf, mind_ref[:, pl.ds(c, _LANE)])
         Tw = winner // block
-        mw = lax.dynamic_slice(mind, (Tw * block,), (block,))
-        tmin = lax.dynamic_update_slice(tmin, jnp.min(mw)[None], (Tw,))
-        order = lax.dynamic_update_slice(order, winner[None], (t,))
-        edges = lax.dynamic_update_slice(edges, best[None], (t,))
-        return mind, tmin, pend, nfold, order, edges, stats, winner
+        tmin = jnp.where(blk_iota == Tw,
+                         jnp.min(mind_ref[:, pl.ds(tile_start(Tw), block)]),
+                         tmin)
+        order_ref[t] = winner
+        edges_ref[t] = best
+        return tmin, pend, nfold, fetches, folds, winner
 
-    stats0 = jnp.zeros((2,), jnp.int32)
-    carry = lax.fori_loop(
-        1, n, step, (mind0, tmin0, pend0, nfold0, order0, edges0, stats0, i0))
-    order_ref[...] = carry[4]
-    edges_ref[...] = carry[5]
-    stats_ref[...] = carry[6]
+    carry = (tmin_ref[...], jnp.full((nblk, 1), inf),
+             jnp.zeros((nblk, 1), jnp.int32), jnp.int32(0), jnp.int32(0), i0)
+    carry = lax.fori_loop(1, n, step, carry)
+    stats_ref[0] = carry[3]
+    stats_ref[1] = carry[4]
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "form", "block",
@@ -329,7 +377,7 @@ def prim_persist_pallas(
 ):
     """Exact VAT ordering of X in ONE persistent pallas_call.
 
-    Pads X once (``prim_stream.pad_points``), precomputes the per-tile
+    Pads X once (to whole tiles and 128 lanes), precomputes the per-tile
     pruning geometry, and hands everything to the megakernel: the whole
     n-1 step Prim recurrence runs inside the kernel with the frontier
     VMEM-resident and X streamed tile-by-tile from ANY/HBM.
@@ -342,7 +390,8 @@ def prim_persist_pallas(
       form: "gram" (default) or "direct" — the numerics-policy tile
         form (static); the pruning slack is debited per form via
         ``numerics.condition.lb_slack_ulps``.
-      block: X tile length (static); clamped like ``pad_points``.
+      block: X tile length (static); rounded up to a multiple of 128
+        and capped at n rounded up to 128 (``_persist_block``).
       interpret: Pallas interpret mode (the CPU correctness path).
       prune: lazy-Prim tile pruning (static).  False forces the eager
         fold-everything schedule — same outputs bit for bit (the pin
@@ -352,50 +401,58 @@ def prim_persist_pallas(
       (order (n,) i32, edges (n,) f32, stats (2,) i32) — the exact
       ordering/edge trace plus the traffic census [tile fetches, pivot
       row folds].  Eager folding costs (n-1)·nblk tile fetches; the gap
-      to ``stats[0]`` is what pruning saved.  Orderings are
-      bitwise-identical to ``ref.prim_persist_ref`` for every metric
-      (near-tie caveat: under heavy Gram-trick cancellation — e.g.
-      cosine between near-parallel points — 1-ulp differences between
-      this kernel's dot lowering and other engines' can flip exact ties;
-      the two Pallas engines share one tile formula so they never flip
-      against each other).
+      to ``stats[0]`` is what pruning saved.  The spanning tree matches
+      ``ref.prim_persist_ref``'s in weight to f32 rounding; the
+      orderings match except at near-ties, where 1-ulp differences
+      between this kernel's dot lowering and the mirror's can pick the
+      other candidate (bitwise-equal in the CPU tests; on a TPU v5e
+      they part at many near-ties of a large random-order set).  The
+      two Pallas engines share one tile formula, so they never flip
+      against each other.
 
     Callers must keep ``persist_supported(n, d, block=block)`` true —
     ``kernels.ops.prim_persist`` owns that guard.
     """
     check_metric(metric)
     check_form(form)
-    n = X.shape[0]
-    Xp, auxp, n_pad, bn = pad_points(X.astype(jnp.float32), aux, block=block)
+    n, d = X.shape
+    bn = _persist_block(n, block)
+    n_pad = -(-n // bn) * bn
+    n_lane = -(-n_pad // _LANE) * _LANE
+    d_pad = -(-d // _LANE) * _LANE
+    Xp = jnp.pad(X.astype(jnp.float32), ((0, n_pad - n), (0, d_pad - d)))
+    auxp = jnp.pad(aux.astype(jnp.float32), (0, n_lane - n))[None]
     cent, rad = persist_tile_bounds(Xp, n, metric=metric, block=bn)
-    d_pad = Xp.shape[1]
+    i0 = jnp.asarray(i0, jnp.int32)
+    iota = jnp.arange(n_pad)
+    tmin0 = jnp.min(jnp.where((iota >= n) | (iota == i0), jnp.inf,
+                              UNSEEN).reshape(-1, bn), axis=1)
 
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     order, edges, stats = pl.pallas_call(
         functools.partial(_persist_kernel, n=n, metric=metric, form=form,
                           block=bn, prune=prune),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # i0
-            pl.BlockSpec((n_pad,), lambda: (0,)),           # aux
+            smem,                                           # i0
+            pl.BlockSpec((1, n_lane), lambda: (0, 0)),      # aux
             pl.BlockSpec((n_pad // bn, d_pad), lambda: (0, 0)),  # cent
-            pl.BlockSpec((n_pad // bn,), lambda: (0,)),     # rad
-            pl.BlockSpec(memory_space=pltpu.ANY),           # X (streamed)
+            pl.BlockSpec((n_pad // bn, 1), lambda: (0, 0)),  # rad
+            pl.BlockSpec((n_pad // bn, 1), lambda: (0, 0)),  # tmin
+            pl.BlockSpec(memory_space=pl.ANY),              # X (streamed)
         ],
-        out_specs=[
-            pl.BlockSpec((n,), lambda: (0,)),
-            pl.BlockSpec((n,), lambda: (0,)),
-            pl.BlockSpec((2,), lambda: (0,)),
-        ],
+        out_specs=[smem, smem, smem],
         out_shape=[
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((n,), jnp.float32),
             jax.ShapeDtypeStruct((2,), jnp.int32),
         ],
         scratch_shapes=[
+            pltpu.VMEM((1, n_lane), jnp.float32),   # in-band frontier
             pltpu.VMEM((bn, d_pad), jnp.float32),   # streamed X tile
-            pltpu.VMEM((1, d_pad), jnp.float32),    # pivot row
+            pltpu.VMEM((_SUB, d_pad), jnp.float32),  # pivot row's group
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
-    )(jnp.asarray(i0, jnp.int32)[None], auxp, cent, rad, Xp)
+    )(i0[None], auxp, cent, rad[:, None], tmin0[:, None], Xp)
     return order, edges, stats

@@ -20,6 +20,9 @@ Per grid step b (one VMEM tile of B points):
   * the min-update and the per-block masked (min, argmin) pair happen on
     the VPU in the same pass; the tiny (nblk,) cross-block reduction runs
     in the jit'd wrapper, first-index tie-breaking preserved.
+  * TPU layout: the (n,) frontier vectors travel as lane-dense (1, n)
+    rows in (1, B) blocks, and the per-block pair as full lane rows
+    (``prim_update.pair_specs``) — the compiler refuses (1,) blocks.
 
 VMEM budget at the default B=1024, d<=512: X tile 1024*512*4B = 2 MiB
 plus four (B,) vectors — far under the 16 MiB core.  The batched grid
@@ -41,6 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.prim_update import first_argmin, pair_specs, write_pair
+from repro.kernels import ref
 from repro.kernels.ref import check_metric
 from repro.numerics.condition import check_form
 
@@ -49,46 +54,50 @@ _LANE = 128  # MXU/VREG lane width — pad d to a multiple
 
 
 def _tile_pivot_row(x, xq, aux, auxq, metric, form):
-    """((B, d), (1, d), (B,), (1,)) -> (B,) dissimilarities to the pivot.
+    """((B, d), (1, d), (1, B), scalar) -> (1, B) dissimilarities to the pivot.
 
     Mirrors ``kernels.ref.pivot_row_ref`` term for term so the fused path
     reproduces the XLA path's orderings (same formula, same clamps).
     ``form == "direct"`` (euclidean/sqeuclidean under the safe/auto
     numerics policies) replaces the MXU matvec with a broadcast squared
-    -difference reduce — no Gram cancellation, at a VPU-bound cost.
+    -difference reduce — no Gram cancellation, at a VPU-bound cost.  The
+    row comes out lane-dense, (1, B), the layout the frontier state
+    keeps on the TPU.
     """
+    lanes = (1, x.shape[0])
     if metric == "manhattan":
-        return jnp.sum(jnp.abs(x - xq), axis=-1)
+        return jnp.sum(jnp.abs(x - xq), axis=-1).reshape(lanes)
     if form == "direct" and metric != "cosine":
         diff = x - xq
-        sq = jnp.sum(diff * diff, axis=-1)
+        sq = jnp.sum(diff * diff, axis=-1).reshape(lanes)
         return jnp.sqrt(sq) if metric == "euclidean" else sq
     cross = jax.lax.dot_general(            # MXU: (B, d) x (1, d)^T
-        x, xq, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32).reshape(x.shape[0])
-    aq = auxq[0]
+        x, xq, (((1,), (1,)), ((), ())), precision=ref.GRAM_PRECISION,
+        preferred_element_type=jnp.float32).reshape(lanes)
     if metric == "cosine":
-        denom = jnp.maximum(aux * aq, 1e-12)
+        denom = jnp.maximum(aux * auxq, 1e-12)
         return jnp.clip(1.0 - cross / denom, 0.0, 2.0)
-    sq = jnp.maximum(aux + aq - 2.0 * cross, 0.0)
+    sq = jnp.maximum(aux + auxq - 2.0 * cross, 0.0)
     return jnp.sqrt(sq) if metric == "euclidean" else sq
+
+
+def _fold_block(x, xq, aux, auxq, mind, sel, metric, form):
+    """One block's fused step: (new frontier (1, B), min, local argmin)."""
+    row = _tile_pivot_row(x, xq, aux, auxq, metric, form)
+    new = jnp.minimum(mind, row)                # Prim min-update, fused
+    m, i = first_argmin(jnp.where(sel != 0, jnp.inf, new))
+    return new, m, i
 
 
 def _prim_stream_kernel(x_ref, xq_ref, aux_ref, auxq_ref, mind_ref, sel_ref,
                         newmind_ref, minv_ref, mini_ref, *, metric, form):
     b = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32)          # (B, d)
-    xq = xq_ref[...].astype(jnp.float32)        # (1, d)
-    row = _tile_pivot_row(x, xq, aux_ref[...], auxq_ref[...], metric, form)
-    new = jnp.minimum(mind_ref[...], row)       # Prim min-update, fused
+    new, m, i = _fold_block(x, xq_ref[...].astype(jnp.float32),
+                            aux_ref[...], auxq_ref[...], mind_ref[...],
+                            sel_ref[...], metric, form)
     newmind_ref[...] = new
-    masked = jnp.where(sel_ref[...], jnp.inf, new)
-    # plain reductions only — a dynamic masked[argmin] gather is the
-    # least-supported VMEM access pattern in Mosaic, and min(masked) is
-    # the same value (the argmin's element) by definition
-    minv_ref[0] = jnp.min(masked)
-    i = jnp.argmin(masked).astype(jnp.int32)    # block-local, first-index
-    mini_ref[0] = i + b * x.shape[0]
+    write_pair(minv_ref, mini_ref, m, i + b * x.shape[0])
 
 
 def _prim_stream_kernel_batch(x_ref, xq_ref, aux_ref, auxq_ref, mind_ref,
@@ -96,14 +105,11 @@ def _prim_stream_kernel_batch(x_ref, xq_ref, aux_ref, auxq_ref, mind_ref,
                               metric, form):
     j = pl.program_id(1)
     x = x_ref[0].astype(jnp.float32)            # (1, B, d) slab -> (B, d)
-    xq = xq_ref[0].astype(jnp.float32)          # (1, 1, d) slab -> (1, d)
-    row = _tile_pivot_row(x, xq, aux_ref[0], auxq_ref[0], metric, form)
-    new = jnp.minimum(mind_ref[0], row)
+    new, m, i = _fold_block(x, xq_ref[0].astype(jnp.float32), aux_ref[0],
+                            auxq_ref[0], mind_ref[0], sel_ref[0], metric,
+                            form)
     newmind_ref[0] = new
-    masked = jnp.where(sel_ref[0], jnp.inf, new)
-    minv_ref[0, 0] = jnp.min(masked)            # see solo kernel note
-    i = jnp.argmin(masked).astype(jnp.int32)
-    mini_ref[0, 0] = i + j * x.shape[0]
+    write_pair(minv_ref, mini_ref, m, i + j * x.shape[0])
 
 
 def pad_points(X: jax.Array, aux: jax.Array, *, block: int = DEFAULT_BLOCK):
@@ -143,31 +149,28 @@ def _stream_call(Xp, xq, auxp, auxq, mind, selected, *, metric, form, block,
     """
     n_pad, d_pad = Xp.shape
     nblk = n_pad // block
+    out_specs, out_shape = pair_specs(nblk)
+    row = pl.BlockSpec((1, block), lambda b: (0, b))
     new_mind, minv, mini = pl.pallas_call(
         functools.partial(_prim_stream_kernel, metric=metric, form=form),
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((block, d_pad), lambda b: (b, 0)),
             pl.BlockSpec((1, d_pad), lambda b: (0, 0)),
-            pl.BlockSpec((block,), lambda b: (b,)),
-            pl.BlockSpec((1,), lambda b: (0,)),
-            pl.BlockSpec((block,), lambda b: (b,)),
-            pl.BlockSpec((block,), lambda b: (b,)),
+            row,
+            pl.BlockSpec((1, 1), lambda b: (0, 0)),
+            row,
+            row,
         ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda b: (b,)),
-            pl.BlockSpec((1,), lambda b: (b,)),
-            pl.BlockSpec((1,), lambda b: (b,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((nblk,), jnp.float32),
-            jax.ShapeDtypeStruct((nblk,), jnp.int32),
-        ],
+        out_specs=[row, *out_specs],
+        out_shape=[jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
+                   *out_shape],
         interpret=interpret,
-    )(Xp, xq, auxp, auxq, mind, selected)
+    )(Xp, xq, auxp[None], auxq.reshape(1, 1), mind[None],
+      selected.astype(jnp.int32)[None])
+    minv, mini = minv[:, 0, 0], mini[:, 0, 0]
     best = jnp.argmin(minv)         # (nblk,) cross-block pass, negligible
-    return new_mind, minv[best], mini[best]
+    return new_mind[0], minv[best], mini[best]
 
 
 @functools.partial(jax.jit,
@@ -303,6 +306,8 @@ def prim_stream_step_pallas_batch(
     auxq = jax.vmap(
         lambda a, i: jax.lax.dynamic_slice_in_dim(a, i, 1, 0))(auxp, q)
 
+    out_specs, out_shape = pair_specs(nblk, lead=(b,))
+    row = pl.BlockSpec((1, 1, block), lambda bi, j: (bi, 0, j))
     new_mind, minv, mini = pl.pallas_call(
         functools.partial(_prim_stream_kernel_batch, metric=metric,
                           form=form),
@@ -310,23 +315,18 @@ def prim_stream_step_pallas_batch(
         in_specs=[
             pl.BlockSpec((1, block, d_pad), lambda bi, j: (bi, j, 0)),
             pl.BlockSpec((1, 1, d_pad), lambda bi, j: (bi, 0, 0)),
-            pl.BlockSpec((1, block), lambda bi, j: (bi, j)),
-            pl.BlockSpec((1, 1), lambda bi, j: (bi, 0)),
-            pl.BlockSpec((1, block), lambda bi, j: (bi, j)),
-            pl.BlockSpec((1, block), lambda bi, j: (bi, j)),
+            row,
+            pl.BlockSpec((1, 1, 1), lambda bi, j: (bi, 0, 0)),
+            row,
+            row,
         ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda bi, j: (bi, j)),
-            pl.BlockSpec((1, 1), lambda bi, j: (bi, j)),
-            pl.BlockSpec((1, 1), lambda bi, j: (bi, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n_pad), jnp.float32),
-            jax.ShapeDtypeStruct((b, nblk), jnp.float32),
-            jax.ShapeDtypeStruct((b, nblk), jnp.int32),
-        ],
+        out_specs=[row, *out_specs],
+        out_shape=[jax.ShapeDtypeStruct((b, 1, n_pad), jnp.float32),
+                   *out_shape],
         interpret=interpret,
-    )(Xp, xq, auxp, auxq, mind, selected)
+    )(Xp, xq, auxp[:, None], auxq[:, None], mind[:, None],
+      selected.astype(jnp.int32)[:, None])
+    minv, mini = minv[..., 0, 0], mini[..., 0, 0]
     best = jnp.argmin(minv, axis=1)                      # (b,) per lane
     lane = jnp.arange(b)
-    return new_mind, minv[lane, best], mini[lane, best]
+    return new_mind[:, 0], minv[lane, best], mini[lane, best]

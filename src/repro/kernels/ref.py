@@ -21,6 +21,20 @@ from repro.numerics.condition import check_form
 METRICS = ("euclidean", "sqeuclidean", "manhattan", "cosine")
 
 
+#: Precision of every Gram dot (this module's matmuls, the pairwise
+#: tile, the Prim pivot rows).  A TPU runs an f32 dot at the default
+#: precision as one bf16 pass, which leaves ~3 significant digits in
+#: every Gram cross term; HIGHEST keeps f32 on every backend (the CPU
+#: computes f32 either way).  Read at trace time;
+#: ``benchmarks/precision_control.py`` lowers it to show why it is set.
+GRAM_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """f32 matmul at ``GRAM_PRECISION``."""
+    return jnp.matmul(a, b, precision=GRAM_PRECISION)
+
+
 def check_metric(metric: str):
     """Raise ValueError unless ``metric`` names a computable metric.
 
@@ -77,12 +91,12 @@ def pairwise_dissim_ref(X: jax.Array, Y: jax.Array | None = None, *,
             nx = jnp.sum(Xf * Xf, axis=-1)
             ny = jnp.sum(Yf * Yf, axis=-1)
             sq = jnp.maximum(
-                nx[:, None] + ny[None, :] - 2.0 * (Xf @ Yf.T), 0.0)
+                nx[:, None] + ny[None, :] - 2.0 * _dot(Xf, Yf.T), 0.0)
         return jnp.sqrt(sq) if metric == "euclidean" else sq
     if metric == "manhattan":
         return jnp.sum(jnp.abs(Xf[:, None, :] - Yf[None, :, :]), axis=-1)
     # cosine
-    cross = Xf @ Yf.T
+    cross = _dot(Xf, Yf.T)
     nx = jnp.sqrt(jnp.sum(Xf * Xf, axis=-1))
     ny = jnp.sqrt(jnp.sum(Yf * Yf, axis=-1))
     denom = jnp.maximum(nx[:, None] * ny[None, :], 1e-12)
@@ -161,7 +175,7 @@ def row_dissim_ref(X: jax.Array, x: jax.Array, *,
     nx = jnp.sqrt(jnp.sum(Xf * Xf, axis=-1))
     nq = jnp.sqrt(jnp.sum(xf * xf))
     denom = jnp.maximum(nx * nq, 1e-12)
-    return jnp.clip(1.0 - (Xf @ xf) / denom, 0.0, 2.0)
+    return jnp.clip(1.0 - _dot(Xf, xf) / denom, 0.0, 2.0)
 
 
 def metric_aux_ref(X: jax.Array, *, metric: str = "euclidean") -> jax.Array:
@@ -222,7 +236,7 @@ def pivot_row_ref(X: jax.Array, aux: jax.Array, q: jax.Array, *,
         diff = Xf - xq[None, :]
         sq = jnp.sum(diff * diff, axis=-1)
         return jnp.sqrt(sq) if metric == "euclidean" else sq
-    cross = Xf @ xq
+    cross = _dot(Xf, xq)
     aq = jnp.take(aux, q)
     if metric == "cosine":
         denom = jnp.maximum(aux * aq, 1e-12)
@@ -266,7 +280,7 @@ def pivot_row_from_point_ref(X: jax.Array, aux: jax.Array, xq: jax.Array,
         diff = Xf - xqf[None, :]
         sq = jnp.sum(diff * diff, axis=-1)
         return jnp.sqrt(sq) if metric == "euclidean" else sq
-    cross = Xf @ xqf
+    cross = _dot(Xf, xqf)
     if metric == "cosine":
         denom = jnp.maximum(aux * auxq, 1e-12)
         return jnp.clip(1.0 - cross / denom, 0.0, 2.0)

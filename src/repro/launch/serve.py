@@ -19,6 +19,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import ServeConfig, TendencyServer
 
 
@@ -58,6 +59,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny fixed workload (CI-sized)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.smoke:
         args.requests, args.concurrency = 16, 4

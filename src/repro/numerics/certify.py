@@ -129,19 +129,27 @@ GENERATORS = {
 # ------------------------------------------------------------------
 
 def oracle_dissim(X, metric: str) -> np.ndarray:
-    """f64 pairwise dissimilarity by explicit differences (no Gram)."""
+    """f64 pairwise dissimilarity by explicit differences (no Gram).
+
+    The difference tensor is built a block of rows at a time (about
+    64 MiB each), so the oracle runs at real feature widths."""
     Xd = np.asarray(X, np.float64)
-    if metric in ("euclidean", "sqeuclidean"):
-        diff = Xd[:, None, :] - Xd[None, :, :]
-        sq = np.einsum("ijd,ijd->ij", diff, diff)
-        return np.sqrt(sq) if metric == "euclidean" else sq
-    if metric == "manhattan":
-        return np.abs(Xd[:, None, :] - Xd[None, :, :]).sum(axis=-1)
     if metric == "cosine":
         norms = np.sqrt(np.einsum("nd,nd->n", Xd, Xd))
         denom = np.maximum(norms[:, None] * norms[None, :], 1e-300)
         return np.clip(1.0 - (Xd @ Xd.T) / denom, 0.0, 2.0)
-    raise ValueError(f"no f64 oracle for metric {metric!r}")
+    if metric not in ("euclidean", "sqeuclidean", "manhattan"):
+        raise ValueError(f"no f64 oracle for metric {metric!r}")
+    n, d = Xd.shape
+    rows = max(1, (1 << 23) // max(1, n * d))
+    out = np.empty((n, n))
+    for s in range(0, n, rows):
+        diff = Xd[s:s + rows, None, :] - Xd[None, :, :]
+        if metric == "manhattan":
+            out[s:s + rows] = np.abs(diff).sum(axis=-1)
+        else:
+            out[s:s + rows] = np.einsum("ijd,ijd->ij", diff, diff)
+    return np.sqrt(out) if metric == "euclidean" else out
 
 
 def tree_weight(R64: np.ndarray, order) -> float:

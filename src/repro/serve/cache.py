@@ -141,6 +141,11 @@ class ProgramCache:
                               size=len(self._programs),
                               capacity=self._capacity)
 
+    def programs(self) -> dict:
+        """Snapshot of the cached ``{ProgramKey: compiled program}``."""
+        with self._lock:
+            return dict(self._programs)
+
     def clear(self) -> None:
         with self._lock:
             self._programs.clear()

@@ -477,6 +477,11 @@ class TendencyServer:
                               resilience=self._counters.snapshot(
                                   self._breakers))
 
+    def programs(self) -> dict:
+        """The compiled programs the cache holds, by ProgramKey — what a
+        caller inspects to see which kernels the served path runs."""
+        return self._cache.programs()
+
     def breaker_state(self, n: int, d: int, *, metric: str = "euclidean",
                       method: str = "auto",
                       slo_ms: float | None = None) -> str:

@@ -31,7 +31,8 @@ SCRIPT = textwrap.dedent("""
     ckpt.save(ckdir, 1, state)
 
     # phase 2: restore onto a 4x2 mesh (different topology) and continue
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sharding.set_mesh(mesh)
     template = jax.eval_shape(
         lambda: S.init_state(cfg, tc, jax.random.PRNGKey(0)))

@@ -89,7 +89,8 @@ DRYRUN_SMALL = textwrap.dedent("""
     cfg = smoke_config("phi3.5-moe-42b-a6.6b").replace(
         d_model=64, n_heads=4, n_kv_heads=4, head_dim=16)
     shape = ShapeConfig("t", 64, 8, "train")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sharding.set_mesh(mesh)
     tc = TrainConfig()
     specs = input_specs(cfg, shape)
