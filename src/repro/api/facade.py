@@ -62,7 +62,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro import core
+from repro import core, spans
 from repro.api import registry
 from repro.api.metrics import as_dissimilarity, validate_metric
 from repro.api.registry import SMALL_N, RungOptions, select_method
@@ -211,6 +211,7 @@ class FastVAT:
 
     # ------------------------------------------------------------- fit ----
 
+    @spans.span("fastvat.fit")
     def fit(self, X, *, encoder=None) -> "FastVAT":
         """Run the resolved rung on one dataset.
 
@@ -470,6 +471,7 @@ class FastVAT:
                               block_score=float(score), k_est=int(k_est),
                               clustered=clustered, batch_index=batch_index)
 
+    @spans.span("fastvat.assess")
     def assess(self, key: jax.Array | None = None):
         """Machine-checkable tendency report: Hopkins + block structure.
 

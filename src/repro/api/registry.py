@@ -40,7 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro import core
+from repro import core, spans
 from repro.api.result import SALT_FIT, ResultMeta, TendencyResult
 from repro.kernels import ops as kops
 
@@ -310,8 +310,10 @@ def select_method(n: int, *, precomputed: bool = False,
 # ---------------------------------------------------------------------
 
 def _as_f32(X) -> jax.Array:
-    return X if isinstance(X, jax.Array) else jnp.asarray(
-        np.asarray(X, np.float32))
+    if isinstance(X, jax.Array):
+        return X
+    with spans.span("device.upload"):
+        return jnp.asarray(np.asarray(X, np.float32))
 
 
 def _vat_result(data, meta: ResultMeta, opts: RungOptions) -> core.VATResult:

@@ -31,6 +31,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import spans
+
 #: Smallest point count a tendency assessment is defined for.
 MIN_POINTS = 4
 
@@ -55,6 +57,7 @@ def _as_real_array(X, name: str) -> np.ndarray:
     return arr
 
 
+@spans.span("api.admission")
 def validate_points(X, *, batched: bool = False, name: str = "X",
                     metric: str | None = None) -> None:
     """Admission-check an (n, d) point matrix (or (b, n, d) stack).
@@ -128,6 +131,7 @@ def validate_points(X, *, batched: bool = False, name: str = "X",
                 "eps-guard semantics")
 
 
+@spans.span("api.admission")
 def validate_dissimilarity(D, *, name: str = "D") -> None:
     """Admission-check a precomputed dissimilarity (finite values only;
     shape/symmetry checks stay in ``metrics.as_dissimilarity``)."""

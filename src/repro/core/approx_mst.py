@@ -62,6 +62,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.kernels import ops as kops
 from repro.kernels.ref import check_metric, pairwise_dissim_ref
 
@@ -205,6 +206,7 @@ def _rowwise_dissim_np(A: np.ndarray, B: np.ndarray, metric: str):
     return np.clip(1.0 - np.sum(A * B, axis=1) / denom, 0.0, 2.0)
 
 
+@spans.span("approx.boruvka")
 def boruvka_mst(idx, dist, *, X=None, metric: str = "euclidean"):
     """MST of a directed kNN graph + connectivity repair.
 
@@ -226,10 +228,11 @@ def boruvka_mst(idx, dist, *, X=None, metric: str = "euclidean"):
       weight contribution.
     """
     check_metric(metric)
-    n, k = np.asarray(idx).shape
+    idx = spans.readback(idx, np.int32)
+    n, k = idx.shape
     rows = np.repeat(np.arange(n, dtype=np.int32), k)
-    flat_i = np.asarray(idx, np.int32).ravel()
-    flat_d = np.asarray(dist, np.float32).ravel()
+    flat_i = idx.ravel()
+    flat_d = spans.readback(dist, np.float32).ravel()
     src = jnp.asarray(np.concatenate([rows, flat_i]))
     dst = jnp.asarray(np.concatenate([flat_i, rows]))
     w = jnp.asarray(np.concatenate([flat_d, flat_d]))
@@ -240,15 +243,15 @@ def boruvka_mst(idx, dist, *, X=None, metric: str = "euclidean"):
     cap = int(math.ceil(math.log2(max(n, 2)))) + 2
     while passes < cap:
         comp, va, vb, pw, rec = _boruvka_pass(comp, src, dst, w)
-        recn = np.asarray(rec)
+        recn = spans.readback(rec)
         if not recn.any():
             break
         passes += 1
-        es.append(np.asarray(va)[recn])
-        ed.append(np.asarray(vb)[recn])
-        ew.append(np.asarray(pw)[recn])
+        es.append(spans.readback(va)[recn])
+        ed.append(spans.readback(vb)[recn])
+        ew.append(spans.readback(pw)[recn])
 
-    comp_np = np.asarray(comp)
+    comp_np = spans.readback(comp)
     roots = np.unique(comp_np)
     ncomp = int(roots.size)
     repair_w = 0.0
@@ -257,13 +260,13 @@ def boruvka_mst(idx, dist, *, X=None, metric: str = "euclidean"):
             raise ValueError(
                 "kNN graph is disconnected; pass X so the spanning repair "
                 "can compute fallback edges")
-        Xn = np.asarray(X, np.float32)
+        Xn = spans.readback(X, np.float32)
         reps = np.full(n, n, np.int64)
         np.minimum.at(reps, comp_np, np.arange(n))
         reps = reps[roots]                       # min vertex per component
         if ncomp <= REPAIR_MAX_C:
-            R = np.asarray(kops.pairwise_dist(jnp.asarray(Xn[reps]),
-                                              metric=metric))
+            R = spans.readback(kops.pairwise_dist(jnp.asarray(Xn[reps]),
+                                                  metric=metric))
             extra = _prim_edges_np(R)
             ra = reps[[a for a, _, _ in extra]]
             rb = reps[[b for _, b, _ in extra]]
@@ -286,6 +289,7 @@ def boruvka_mst(idx, dist, *, X=None, metric: str = "euclidean"):
     return tree, passes, ncomp, repair_w
 
 
+@spans.span("approx.walk")
 def mst_vat_order(n: int, tree: MSTEdges, i0: int):
     """VAT ordering of a spanning tree: Prim restricted to tree edges.
 
@@ -402,7 +406,7 @@ def knn_graph_anchored(X, *, k: int, metric: str = "euclidean",
       probed cells could not fill hold (inf, -1).
     """
     check_metric(metric)
-    Xn = np.asarray(X, np.float32)
+    Xn = spans.readback(X, np.float32)
     n, _ = Xn.shape
     c = anchors if anchors is not None else max(32, int(round(math.sqrt(n))))
     c = min(c, n)
@@ -422,7 +426,7 @@ def knn_graph_anchored(X, *, k: int, metric: str = "euclidean",
         D = kops.pairwise_dist(jnp.asarray(xb), A, metric=metric,
                                use_pallas=use_pallas)
         _, pid = jax.lax.top_k(-D, probes)
-        probe_idx[s0:s0 + rows] = np.asarray(pid, np.int32)[:rows]
+        probe_idx[s0:s0 + rows] = spans.readback(pid, np.int32)[:rows]
 
     # CSR views: candidates by primary cell, queries by each probe slot.
     primary = probe_idx[:, 0]
@@ -461,8 +465,8 @@ def knn_graph_anchored(X, *, k: int, metric: str = "euclidean",
         gd, gi = _cell_topk(jnp.asarray(Xq), jnp.asarray(Xc),
                             jnp.asarray(qid), jnp.asarray(cid),
                             metric=metric, kk=kk)
-        gd = np.asarray(gd, np.float32)[:q.size]
-        gi = np.asarray(gi, np.int64)[:q.size]
+        gd = spans.readback(gd, np.float32)[:q.size]
+        gi = spans.readback(gi, np.int64)[:q.size]
         gi = np.where(np.isfinite(gd), gi, -1)
         part_d[q, slot, :kk] = gd
         part_i[q, slot, :kk] = gi
@@ -506,7 +510,7 @@ def approx_vat(X, *, k: int = 15, metric: str = "euclidean",
     if knn_mode not in ("auto", "exact", "anchored"):
         raise ValueError(f"knn_mode must be auto|exact|anchored, "
                          f"got {knn_mode!r}")
-    Xn = np.asarray(X, np.float32)
+    Xn = spans.readback(X, np.float32)
     n = Xn.shape[0]
     if n == 1:
         stats = ApproxStats(k=0, mode="exact", n_passes=0, components=1,
@@ -516,17 +520,18 @@ def approx_vat(X, *, k: int = 15, metric: str = "euclidean",
                                stats)
     k_eff = min(k, n - 1)
     exact = knn_mode == "exact" or (knn_mode == "auto" and n <= EXACT_KNN_N)
-    if exact:
-        dj, ij = kops.knn_graph(jnp.asarray(Xn), k=k_eff, metric=metric,
-                                use_pallas=use_pallas, block=block)
-        dist = np.asarray(dj)
-        idx = np.asarray(ij, np.int64)
-        mode = "exact"
-    else:
-        dist, idx = knn_graph_anchored(Xn, k=k_eff, metric=metric,
-                                       anchors=anchors, probes=probes,
-                                       use_pallas=use_pallas, rng=rng)
-        mode = "anchored"
+    with spans.span("approx.knn"):
+        if exact:
+            dj, ij = kops.knn_graph(jnp.asarray(Xn), k=k_eff, metric=metric,
+                                    use_pallas=use_pallas, block=block)
+            dist = spans.readback(dj)
+            idx = spans.readback(ij, np.int64)
+            mode = "exact"
+        else:
+            dist, idx = knn_graph_anchored(Xn, k=k_eff, metric=metric,
+                                           anchors=anchors, probes=probes,
+                                           use_pallas=use_pallas, rng=rng)
+            mode = "anchored"
 
     finite = np.isfinite(dist) & (idx >= 0)
     radius = np.where(finite, dist, -np.inf).max(axis=1)

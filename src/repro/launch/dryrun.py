@@ -8,7 +8,7 @@ platform devices stand in for two v5e-256 pods.  For each cell we jit the
 real train_step / serve_step against ShapeDtypeStruct inputs with the
 production shardings, ``.lower().compile()`` it, and record
 ``memory_analysis()`` / ``cost_analysis()`` plus the HLO collective mix
-into a JSON the roofline analysis consumes.
+into a JSON.
 
 Usage:
   python -m repro.launch.dryrun --arch gemma-2b --shape train_4k
@@ -48,8 +48,7 @@ def parse_collectives(hlo: str) -> dict:
     """Sum result-shape bytes of every collective op in (partitioned) HLO.
 
     Shapes in the partitioned module are per-device; ops inside while
-    bodies are counted once per appearance — the roofline multiplies by
-    trip counts analytically (see launch/roofline.py).
+    bodies are counted once per appearance.
     """
     out: dict[str, dict] = {}
     for m in _COLL_RE.finditer(hlo):
@@ -93,7 +92,7 @@ def _train_config(cfg, momentum: bool = True) -> TrainConfig:
 
 def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                overrides: dict | None = None):
-    """Build (lowered, mesh, cfg) for one cell — shared with roofline."""
+    """Build (lowered, mesh, cfg) for one cell."""
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     param_dtype = jnp.bfloat16

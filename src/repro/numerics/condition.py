@@ -64,7 +64,7 @@ import dataclasses
 
 import numpy as np
 
-from repro import faults
+from repro import faults, spans
 
 #: Largest condition estimate at which the Gram-form tiles are
 #: certifiably order-safe (see module docstring for the derivation:
@@ -304,6 +304,7 @@ def _quantize_bf16(X: np.ndarray) -> np.ndarray:
     return rounded.astype(np.uint32).view(np.float32).reshape(X.shape)
 
 
+@spans.span("numerics.prepass")
 def resolve(X, *, metric: str, policy: NumericsPolicy | str | None = None,
             batched: bool = False):
     """The host pre-pass: turn (data, metric, policy) into a plan.
