@@ -12,7 +12,9 @@ one typed error:
 callers catching ``ValueError`` keep working, while the serving layer
 can count admission rejects separately from scheduling errors.
 
-Checks (all O(n·d), one vectorized pass — skippable via
+Checks (all O(n·d), folded in one pass over row chunks of about 1 MiB
+of f64 each, so admission allocates nothing that grows with n and an
+np.memmap table is read, not copied — skippable via
 ``FastVAT(validate=False)`` for trusted hot loops):
 
   * dtype is real-numeric (bool/int/float; complex, strings and object
@@ -32,6 +34,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro import spans
+from repro.numerics.condition import row_chunks
 
 #: Smallest point count a tendency assessment is defined for.
 MIN_POINTS = 4
@@ -81,54 +84,72 @@ def validate_points(X, *, batched: bool = False, name: str = "X",
         # shape errors stay plain ValueErrors at the callers; admission
         # only guards value-level poison.  Tolerate and let them handle.
         return
-    n_axis = 1 if batched else 0
-    n = arr.shape[n_axis]
+    n = arr.shape[-2]
     if n < MIN_POINTS:
         raise InvalidInput(
             "too_few_points",
             f"{name} has n={n} points; a tendency assessment needs at "
             f"least {MIN_POINTS}")
-    if arr.dtype.kind == "f" and not bool(np.isfinite(arr).all()):
+    stack = arr if batched else arr[None]
+    floating = arr.dtype.kind == "f"
+    cosine = metric == "cosine"
+    # A sum of non-negative squares is 0 iff every square is: for
+    # f16/f32 and integers that is "every entry is 0" (their smallest
+    # nonzero square is a normal f64); wider floats' squares can
+    # underflow, so they keep the f64 norms.
+    wide = floating and arr.dtype.itemsize > 4
+    varied = np.zeros(stack.shape[0], bool)
+    zero_lanes = np.zeros(stack.shape[0], bool)
+    zero_rows = []
+    for start, c in row_chunks(stack):
+        if floating and not bool(np.isfinite(c).all()):
+            if batched:
+                bad = np.flatnonzero(~np.isfinite(arr).all(axis=(1, 2)))
+                where = f" (lane(s) {bad.tolist()})"
+            else:
+                where = ""
+            raise InvalidInput(
+                "non_finite",
+                f"{name} contains non-finite values (NaN/Inf){where}; "
+                "clean the data or pass validate=False to skip admission "
+                "checks")
+        if not varied.all():
+            # a column holds two distinct values iff some row differs
+            # from the lane's first row; comparing (never subtracting)
+            # cannot wrap on narrow integer dtypes
+            varied |= (c != stack[:, :1]).any(axis=(1, 2))
+        if cosine:
+            if wide:
+                c64 = np.asarray(c, np.float64)
+                zero = np.einsum("...nd,...nd->...n", c64, c64) == 0.0
+            else:
+                zero = ~c.any(axis=-1)
+            zero_lanes |= zero.any(axis=-1)
+            if not batched:
+                zero_rows.extend((np.flatnonzero(zero[0]) + start).tolist())
+    dead = np.flatnonzero(~varied)
+    if dead.size:
         if batched:
-            bad = np.flatnonzero(
-                ~np.isfinite(arr).all(axis=(1, 2)))
-            where = f" (lane(s) {bad.tolist()})"
-        else:
-            where = ""
-        raise InvalidInput(
-            "non_finite",
-            f"{name} contains non-finite values (NaN/Inf){where}; clean "
-            "the data or pass validate=False to skip admission checks")
-    spread = np.ptp(arr, axis=n_axis)
-    if batched:
-        dead = np.flatnonzero(~(spread.max(axis=-1) > 0))
-        if dead.size:
             raise InvalidInput(
                 "degenerate",
                 f"{name} lane(s) {dead.tolist()} have zero variance "
                 "(all points identical) — tendency is undefined")
-    elif not bool(spread.max() > 0):
         raise InvalidInput(
             "degenerate",
             f"{name} has zero variance (all {n} points identical) — "
             "tendency is undefined")
-    if metric == "cosine":
-        norms = np.einsum("...nd,...nd->...n", np.asarray(arr, np.float64),
-                          np.asarray(arr, np.float64))
-        zero = norms == 0.0
-        if bool(zero.any()):
-            if batched:
-                lanes = np.flatnonzero(zero.any(axis=-1))
-                where = f" (lane(s) {lanes.tolist()})"
-            else:
-                where = f" (row(s) {np.flatnonzero(zero).tolist()})"
-            raise InvalidInput(
-                "zero_norm",
-                f"{name} has zero-norm rows{where}; cosine dissimilarity "
-                "is undefined for them (the kernels' eps-guard would "
-                "silently map them to distance 1.0 from everything) — "
-                "drop the rows or pass validate=False to keep the "
-                "eps-guard semantics")
+    if zero_lanes.any():
+        if batched:
+            where = f" (lane(s) {np.flatnonzero(zero_lanes).tolist()})"
+        else:
+            where = f" (row(s) {zero_rows})"
+        raise InvalidInput(
+            "zero_norm",
+            f"{name} has zero-norm rows{where}; cosine dissimilarity "
+            "is undefined for them (the kernels' eps-guard would "
+            "silently map them to distance 1.0 from everything) — "
+            "drop the rows or pass validate=False to keep the "
+            "eps-guard semantics")
 
 
 @spans.span("api.admission")

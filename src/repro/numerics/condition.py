@@ -8,10 +8,14 @@ larger than typical inter-point gaps and silently reorders near-ties.
 This module decides, per fit and on the host (before any program is
 traced), three things:
 
-  1. **How bad is it?**  ``condition_stats`` streams a cheap pre-pass
-     over X: max‖x‖², the coordinate spread, a pairwise-gap proxy (the
-     median nonzero squared distance over a deterministic strided
-     subsample), and the condition estimate
+  1. **How bad is it?**  ``condition_stats`` streams X in row chunks of
+     about 1 MiB of f64 each (``row_chunks``), so the pre-pass holds no
+     f64 copy of X and its memory does not grow with n: max‖x‖², the
+     centred max‖x − mean‖² and the coordinate spread (the centred pass
+     runs only where something reads it: ``resolve`` reads it only to
+     certify bf16 storage on a ``CONDITIONED_METRICS`` metric), a
+     pairwise-gap proxy (the median nonzero squared distance over a
+     deterministic strided subsample), and the condition estimate
 
          κ = max‖x‖² / gap_proxy
 
@@ -84,6 +88,9 @@ CONDITIONED_METRICS = ("euclidean", "sqeuclidean", "manhattan")
 #: Rows the gap-proxy subsample is capped at — the pre-pass must stay
 #: O(n·d + s²) with s tiny next to any fit.
 _GAP_SAMPLE = 256
+
+#: f64 bytes one chunk of the host pre-pass holds (``row_chunks``).
+_CHUNK_BYTES = 1 << 20
 
 _F32_EPS = float(np.finfo(np.float32).eps)
 
@@ -208,18 +215,46 @@ class ConditionStats:
     kappa_centered: float
 
 
-def _stats_one(X: np.ndarray) -> ConditionStats:
-    Xd = np.asarray(X, np.float64)
-    sq = np.einsum("nd,nd->n", Xd, Xd)
-    max_sq = float(np.max(sq)) if sq.size else 0.0
-    mean = np.mean(Xd, axis=0)
-    C = Xd - mean
-    csq = np.einsum("nd,nd->n", C, C)
-    centered_max_sq = float(np.max(csq)) if csq.size else 0.0
-    spread = float(np.max(np.ptp(Xd, axis=0))) if Xd.size else 0.0
-    n = Xd.shape[0]
+def row_chunks(X: np.ndarray):
+    """Yield ``(start, X[..., start:start + rows, :])`` over the row
+    axis (axis -2) of an (n, d) matrix or (b, n, d) stack.
+
+    ``rows`` is sized so one chunk holds about ``_CHUNK_BYTES`` once cast
+    to f64, whatever n, d and b are: the host pre-pass folds its
+    statistics chunk by chunk and never allocates a temporary that grows
+    with n (np.memmap tables included).
+    """
+    n = X.shape[-2]
+    width = max(1, X.size // n) if n else 1
+    rows = max(1, _CHUNK_BYTES // (8 * width))
+    for start in range(0, n, rows):
+        yield start, X[..., start:start + rows, :]
+
+
+def _stats_one(X: np.ndarray, centered: bool) -> ConditionStats:
+    # Every field equals the whole-array f64 formula bit for bit: row
+    # sums and the column extremes do not depend on the chunking, and
+    # the f64 mean is one buffered reduction over X, as on an f64 copy.
+    n = X.shape[0]
+    mean = np.mean(X, axis=0, dtype=np.float64) if centered and n else None
+    sq_max, csq_max, lo, hi = [], [], None, None
+    for _, c in row_chunks(X):
+        c = np.asarray(c, np.float64)
+        sq_max.append(np.max(np.einsum("nd,nd->n", c, c)))
+        if centered:
+            C = c - mean
+            csq_max.append(np.max(np.einsum("nd,nd->n", C, C)))
+            cmin, cmax = np.min(c, axis=0), np.max(c, axis=0)
+            lo = cmin if lo is None else np.minimum(lo, cmin)
+            hi = cmax if hi is None else np.maximum(hi, cmax)
+    max_sq = float(np.max(sq_max)) if sq_max else 0.0
+    if centered:
+        centered_max_sq = float(np.max(csq_max)) if csq_max else 0.0
+        spread = float(np.max(hi - lo)) if X.size else 0.0
+    else:  # not read by the caller: left out of the pass
+        centered_max_sq = spread = float("nan")
     stride = max(1, n // _GAP_SAMPLE)
-    S = Xd[::stride][:_GAP_SAMPLE]
+    S = np.asarray(X[::stride][:_GAP_SAMPLE], np.float64)
     ssq = np.einsum("nd,nd->n", S, S)
     G = ssq[:, None] + ssq[None, :] - 2.0 * (S @ S.T)
     np.maximum(G, 0.0, out=G)
@@ -245,13 +280,22 @@ def condition_stats(X) -> ConditionStats:
     reports the worst lane (max κ, max scales, min gap): conditioning
     is all-or-nothing per fit, so the plan must be safe for every lane.
     """
-    arr = np.asarray(X, np.float64)
+    return _condition_stats(X, centered=True)
+
+
+def _condition_stats(X, *, centered: bool) -> ConditionStats:
+    """``condition_stats``; with ``centered=False`` the centred pass is
+    skipped and ``centered_max_sq``, ``spread`` and ``kappa_centered``
+    read NaN (``resolve`` reads them only to certify bf16 storage)."""
+    arr = np.asarray(X)
+    if arr.dtype.kind not in "biuf":
+        arr = np.asarray(arr, np.float64)
     if arr.ndim == 2:
-        return _stats_one(arr)
+        return _stats_one(arr, centered)
     if arr.ndim != 3:
         raise ValueError(f"condition_stats wants (n, d) or (b, n, d), "
                          f"got shape {arr.shape}")
-    per = [_stats_one(lane) for lane in arr]
+    per = [_stats_one(lane, centered) for lane in arr]
     return ConditionStats(
         max_sq_norm=max(s.max_sq_norm for s in per),
         centered_max_sq=max(s.centered_max_sq for s in per),
@@ -332,7 +376,10 @@ def resolve(X, *, metric: str, policy: NumericsPolicy | str | None = None,
         raise ValueError(f"resolve(batched=True) wants (b, n, d), got "
                          f"shape {Xf.shape}")
     conditionable = metric in CONDITIONED_METRICS
-    stats = condition_stats(Xf)
+    # kappa_centered is read only to certify bf16 storage on a
+    # conditioned metric; every other plan needs κ alone
+    stats = _condition_stats(
+        Xf, centered=conditionable and policy.dtype == "bf16")
 
     if policy.mode == "fast":
         condition = False

@@ -5,6 +5,9 @@ Pins the whole contract:
 
 * the policy/statistics layer (κ derivation constants, ``as_policy``
   coercion, ``condition_stats`` on solo + batched + pathological input);
+* the streamed pre-pass: every ``ConditionStats`` field bitwise equal
+  to the whole-array f64 formula, ``resolve`` unchanged, admission
+  verdicts across chunk edges, and memory bounded below the table's;
 * the conditioning transform's EXACTNESS properties (power-of-2 scale,
   bitwise shift cancellation on exact-arithmetic grid data);
 * ``resolve`` planning per mode (fast / safe / auto × metric);
@@ -19,6 +22,11 @@ Pins the whole contract:
 * the serving layer: the resolved plan as ProgramKey material, the
   per-request ``NumericsReport``, and the resilience fallback counter.
 """
+import dataclasses
+import re
+import tracemalloc
+import zlib
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -27,15 +35,18 @@ from hypothesis import strategies as st
 from _numerics_data import ADVERSARIAL_NAMES, adversarial, grid_clusters
 from repro import faults
 from repro.api import FastVAT
-from repro.api.validation import InvalidInput
+from repro.api.validation import InvalidInput, validate_points
 from repro.core.streaming import StreamingVAT
 from repro.numerics import (CONDITIONED_METRICS, KAPPA_BF16, KAPPA_SAFE,
-                            NumericsPolicy, NumericsReport, as_policy,
+                            ConditionStats, NumericsPolicy,
+                            NumericsReport, as_policy,
                             condition_stats, condition_transform,
                             lb_slack_ulps, resolve)
 from repro.numerics.certify import (certify_fit, ordering_excess,
                                     oracle_dissim, sweep)
-from repro.numerics.condition import _quantize_bf16
+from repro.numerics import condition
+from repro.numerics.condition import (_CHUNK_BYTES, _condition_stats,
+                                      _quantize_bf16)
 from repro.serve import ServeConfig, TendencyServer, resolve_key
 
 
@@ -103,6 +114,146 @@ def test_condition_stats_degenerate_inputs():
     # all-identical nonzero points: finite norm over zero gap -> inf
     same = condition_stats(np.ones((8, 3), np.float32) * 5.0)
     assert same.kappa == float("inf")
+
+
+# ------------------------------------- the streamed pre-pass (chunks) ----
+
+def _stats_whole_array(X):
+    """The whole-array f64 formula the chunked pre-pass replaced: the
+    reference every ``ConditionStats`` field is pinned to bit for bit."""
+    Xd = np.asarray(X, np.float64)
+    sq = np.einsum("nd,nd->n", Xd, Xd)
+    max_sq = float(np.max(sq)) if sq.size else 0.0
+    C = Xd - np.mean(Xd, axis=0)
+    csq = np.einsum("nd,nd->n", C, C)
+    centered_max_sq = float(np.max(csq)) if csq.size else 0.0
+    spread = float(np.max(np.ptp(Xd, axis=0))) if Xd.size else 0.0
+    n = Xd.shape[0]
+    S = Xd[::max(1, n // 256)][:256]
+    ssq = np.einsum("nd,nd->n", S, S)
+    G = ssq[:, None] + ssq[None, :] - 2.0 * (S @ S.T)
+    np.maximum(G, 0.0, out=G)
+    off = G[np.triu_indices(S.shape[0], k=1)]
+    nz = off[off > 0.0]
+    gap = float(np.median(nz)) if nz.size else 0.0
+    kappa = max_sq / gap if gap > 0.0 else (0.0 if max_sq == 0.0
+                                            else float("inf"))
+    kc = centered_max_sq / gap if gap > 0.0 else (
+        0.0 if centered_max_sq == 0.0 else float("inf"))
+    return ConditionStats(max_sq, centered_max_sq, spread, gap, kappa, kc)
+
+
+def _condition_stats_whole_array(X):
+    arr = np.asarray(X, np.float64)
+    if arr.ndim == 2:
+        return _stats_whole_array(arr)
+    per = [_stats_whole_array(lane) for lane in arr]
+    return ConditionStats(
+        max(s.max_sq_norm for s in per), max(s.centered_max_sq for s in per),
+        max(s.spread for s in per), min(s.gap_proxy for s in per),
+        max(s.kappa for s in per), max(s.kappa_centered for s in per))
+
+
+def _bits(*values):
+    return np.asarray(values, np.float64).tobytes()
+
+
+def _chunk_rows(d):
+    return _CHUNK_BYTES // (8 * d)
+
+
+def _prepass_case(name):
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    r13, r768 = _chunk_rows(13), _chunk_rows(768)
+    if name == "below-one-chunk":
+        return rng.normal(size=(100, 13)).astype(np.float32)
+    if name == "d13-ragged":
+        return (rng.normal(size=(2 * r13 + 37, 13)) * 3 + 2).astype(np.float32)
+    if name == "d768-ragged":
+        return rng.normal(size=(3 * r768 + 5, 768)).astype(np.float32)
+    if name == "d768-exact-chunks":
+        return rng.normal(size=(2 * r768, 768)).astype(np.float32)
+    if name == "far-offset":
+        return np.tile(_near_origin(), (600, 1)) + np.float32(1.0e4)
+    if name == "batched":
+        return np.stack([_near_origin(n=r13 + 3, d=13, seed=s) + off
+                         for s, off in ((5, 0.0), (6, 1.0e4), (7, -3.0))])
+    if name == "f64":
+        return rng.normal(size=(r13 + 11, 13)) * 1e3 + 7.0
+    if name == "f64-far-offset":   # f64 sums round: the mean's order shows
+        return rng.normal(size=(2 * r13 + 3, 13)) + 1.0e4
+    if name == "int16":
+        return rng.integers(-30000, 30000, size=(r13 + 9, 13)
+                            ).astype(np.int16)
+    if name == "int64-batched":
+        return rng.integers(-10**6, 10**6, size=(2, 300, 13))
+    if name == "fortran-order":
+        return np.asfortranarray(
+            rng.normal(size=(2 * r13 + 1, 13)).astype(np.float32) + 50.0)
+    raise KeyError(name)
+
+
+_PREPASS_CASES = ("below-one-chunk", "d13-ragged", "d768-ragged",
+                  "d768-exact-chunks", "far-offset", "batched", "f64",
+                  "f64-far-offset", "int16", "int64-batched", "fortran-order")
+
+
+@pytest.mark.parametrize("case", _PREPASS_CASES)
+def test_condition_stats_streamed_matches_whole_array(case):
+    """The chunked pre-pass pins every ``ConditionStats`` field BITWISE
+    to the whole-array f64 formula; without the centred pass (what
+    ``resolve`` runs for an unconditioned metric) the fields it keeps
+    are the same bits and the ones it skips read NaN."""
+    X = _prepass_case(case)
+    ref = _condition_stats_whole_array(X)
+    got = condition_stats(X)
+    assert _bits(*dataclasses.astuple(got)) == \
+        _bits(*dataclasses.astuple(ref))
+    lean = _condition_stats(X, centered=False)
+    assert _bits(lean.max_sq_norm, lean.gap_proxy, lean.kappa) == \
+        _bits(ref.max_sq_norm, ref.gap_proxy, ref.kappa)
+    assert np.isnan([lean.centered_max_sq, lean.spread,
+                     lean.kappa_centered]).all()
+    if case == "far-offset":
+        assert got.kappa > KAPPA_SAFE
+
+
+@pytest.mark.parametrize("case", ["d13-ragged", "far-offset", "batched",
+                                  "f64", "d768-ragged"])
+@pytest.mark.parametrize("metric,policy", [
+    ("cosine", "auto"), ("euclidean", "auto"), ("manhattan", "safe"),
+    ("euclidean", NumericsPolicy(mode="auto", dtype="bf16")),
+    ("cosine", NumericsPolicy(mode="safe", dtype="bf16"))])
+def test_resolve_unchanged_by_the_streamed_prepass(case, metric, policy,
+                                                   monkeypatch):
+    """``resolve``'s output array and ``NumericsReport`` are the ones
+    the whole-array statistics give, for every metric and policy."""
+    X = _prepass_case(case)
+    batched = X.ndim == 3
+    Xo, rep = resolve(X, metric=metric, policy=policy, batched=batched)
+    monkeypatch.setattr(
+        condition, "_condition_stats",
+        lambda X, centered: _condition_stats_whole_array(X))
+    Xr, rep_r = resolve(X, metric=metric, policy=policy, batched=batched)
+    assert rep == rep_r and _bits(rep.kappa) == _bits(rep_r.kappa)
+    assert Xo.dtype == Xr.dtype == np.float32
+    assert Xo.tobytes() == Xr.tobytes()
+
+
+def test_prepass_memory_is_bounded():
+    """Admission plus the statistics on an 8192 x 768 f32 cosine table
+    allocate at peak under a quarter of the table's own bytes: no f64
+    copy, no whole-array temporary."""
+    X = np.random.default_rng(3).normal(size=(8192, 768)).astype(np.float32)
+    tracemalloc.start()
+    try:
+        validate_points(X, metric="cosine")
+        Xo, rep = resolve(X, metric="cosine")
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert Xo is X and rep.form == "gram"
+    assert peak < X.nbytes / 4, (peak, X.nbytes)
 
 
 # ------------------------------------------------------ the transform ----
@@ -357,6 +508,76 @@ def test_cosine_zero_norm_rejected_streaming():
     relaxed = StreamingVAT(cap=16, d=4, metric="cosine", validate=False)
     relaxed.update(chunk)
     assert relaxed.n_seen == 8
+
+
+# ------------------------------------- admission across chunk edges ----
+
+def _cosine_table(n, d=768, seed=21):
+    return np.random.default_rng(seed).normal(size=(n, d)).astype(np.float32)
+
+
+@pytest.mark.parametrize("rows", [
+    [_chunk_rows(768) - 1], [_chunk_rows(768)],
+    [_chunk_rows(768) - 1, _chunk_rows(768)]],
+    ids=["last-of-chunk", "first-of-next", "both"])
+def test_zero_norm_row_at_a_chunk_edge(rows):
+    X = _cosine_table(3 * _chunk_rows(768) + 7)
+    X[rows] = 0.0
+    with pytest.raises(InvalidInput,
+                       match=re.escape(f"(row(s) {rows})")) as ei:
+        validate_points(X, metric="cosine")
+    assert ei.value.reason == "zero_norm"
+
+
+def test_zero_norm_subnormal_row_accepted_negative_zero_rejected():
+    X = _cosine_table(2 * _chunk_rows(768) + 3)
+    X[5] = np.float32(1e-45)              # the smallest f32 subnormal
+    validate_points(X, metric="cosine")
+    X[-1] = -0.0
+    with pytest.raises(InvalidInput, match=re.escape(
+            f"(row(s) {[X.shape[0] - 1]})")) as ei:
+        validate_points(X, metric="cosine")
+    assert ei.value.reason == "zero_norm"
+
+
+def test_zero_norm_f64_row_that_squares_to_zero_rejected():
+    """Wider floats keep the f64 norms: a row whose squares underflow
+    reads zero-norm, as the whole-array f64 screen had it."""
+    X = np.random.default_rng(22).normal(size=(40, 5))
+    X[9] = 1e-200
+    with pytest.raises(InvalidInput, match=re.escape("(row(s) [9])")):
+        validate_points(X, metric="cosine")
+
+
+def test_nan_in_the_final_partial_chunk():
+    X = _cosine_table(2 * _chunk_rows(768) + 5)
+    X[-1, 700] = np.nan
+    with pytest.raises(InvalidInput) as ei:
+        validate_points(X, metric="cosine")
+    assert ei.value.reason == "non_finite"
+
+
+def test_zero_norm_batched_names_the_second_lane():
+    Xs = np.stack([_cosine_table(_chunk_rows(768) + 9, seed=s)
+                   for s in (23, 24, 25)])
+    Xs[1, _chunk_rows(768) + 4] = 0.0
+    with pytest.raises(InvalidInput,
+                       match=re.escape("(lane(s) [1])")) as ei:
+        validate_points(Xs, batched=True, metric="cosine")
+    assert ei.value.reason == "zero_norm"
+
+
+def test_int8_full_range_column_is_not_degenerate():
+    """Degeneracy compares rows instead of subtracting column extremes:
+    np.ptp wraps an int8 column spanning -128..127 to -1."""
+    X = np.zeros((8, 2), np.int8)
+    X[::2, 0], X[1::2, 0] = -128, 127
+    validate_points(X)
+    Xs = np.stack([X, np.full((8, 2), 3, np.int8)])
+    with pytest.raises(InvalidInput,
+                       match=re.escape("lane(s) [1]")) as ei:
+        validate_points(Xs, batched=True)
+    assert ei.value.reason == "degenerate"
 
 
 # -------------------------------------------- adversarial properties ----
