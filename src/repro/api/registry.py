@@ -429,16 +429,19 @@ def _flash_order(Xj, meta: ResultMeta, opts: RungOptions):
     if (opts.turbo is None and len(devs) > 1
             and meta.n >= FLASH_SHARD_MIN_N and opts.num_form == "gram"):
         from jax.sharding import Mesh
-        mesh = Mesh(np.array(devs), ("data",))
-        return core.vat_matrix_free_sharded(Xj, mesh, metric=meta.metric,
-                                            use_pallas=meta.use_pallas)
-    return core.vat_matrix_free(Xj, metric=meta.metric,
-                                form=opts.num_form,
-                                use_pallas=meta.use_pallas,
-                                turbo=True if opts.turbo is None
-                                else opts.turbo)
+        with spans.span("exact.sharded"):
+            mesh = Mesh(np.array(devs), ("data",))
+            return core.vat_matrix_free_sharded(Xj, mesh, metric=meta.metric,
+                                                use_pallas=meta.use_pallas)
+    with spans.span("exact.solo"):
+        return core.vat_matrix_free(Xj, metric=meta.metric,
+                                    form=opts.num_form,
+                                    use_pallas=meta.use_pallas,
+                                    turbo=True if opts.turbo is None
+                                    else opts.turbo)
 
 
+@spans.span("render.band")
 def _band_render(Xj: jax.Array, order: jax.Array, meta: ResultMeta,
                  opts: RungOptions) -> TendencyResult:
     """bigvat-style banded rendering of a full-n ordering.

@@ -2,6 +2,10 @@
 record."""
 from __future__ import annotations
 
+import os
+import subprocess
+import sys
+import textwrap
 import threading
 from collections import Counter
 
@@ -189,3 +193,40 @@ def test_validate_false_skips_the_admission_span():
     FastVAT(validate=False).fit(_blobs(60, 3))
     names = [r.name for r in spans.recent()]
     assert "api.admission" not in names and "numerics.prepass" in names
+
+
+def test_flashvat_records_its_engine_and_the_band_render():
+    FastVAT(method="flashvat", sample_size=32).fit(_blobs(300, 4))
+    recs = spans.recent()
+    fit = by_name(recs)["fastvat.fit"]
+    under = [r.name for r in recs if r.parent == fit.id]
+    assert under == ["api.admission", "numerics.prepass", "device.upload",
+                     "exact.solo", "render.band"]
+
+
+# Four CPU devices; the shard threshold is lowered in the subprocess only,
+# so that a small table takes the sharded branch of _flash_order.
+SHARDED_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import numpy as np
+    from repro import FastVAT, spans
+    from repro.api import registry
+    registry.FLASH_SHARD_MIN_N = 64
+    X = np.random.default_rng(0).normal(size=(100, 4)).astype(np.float32)
+    FastVAT(method="flashvat", sample_size=16).fit(X)
+    recs = spans.recent()
+    fit = [r for r in recs if r.name == "fastvat.fit"][0]
+    print("UNDER", ",".join(r.name for r in recs if r.parent == fit.id))
+""")
+
+
+def test_sharded_flashvat_records_exact_sharded():
+    r = subprocess.run([sys.executable, "-c", SHARDED_SCRIPT],
+                       capture_output=True, text=True, timeout=300,
+                       env={"PYTHONPATH": os.path.join(
+                           os.path.dirname(__file__), "..", "src"),
+                            "PATH": os.environ["PATH"],
+                            "JAX_PLATFORMS": "cpu"})
+    assert ("UNDER api.admission,numerics.prepass,device.upload,"
+            "exact.sharded,render.band") in r.stdout, r.stderr[-2000:]
