@@ -355,19 +355,65 @@ def _bucket(size: int) -> int:
     return b
 
 
-@functools.partial(jax.jit, static_argnames=("metric", "kk"))
-def _cell_topk(Xq, Xc, qid, cid, *, metric: str, kk: int):
-    """Top-kk candidates per query within one (padded) anchor cell.
+#: Bytes one ``_cell_topk`` dispatch gathers and tiles over its cells
+#: (f32 query and candidate rows, distance tiles).  Sets the cells per
+#: dispatch of each bucket pair (``_cells_per_dispatch``).
+_CELL_DISPATCH_BYTES = 256 << 20
 
-    Padded candidate columns carry cid = -1 and padded query rows
-    qid = -2 (distinct sentinels so padding never self-matches); both
-    mask to +inf before the top_k, so they can only fill trailing slots
-    of undersized cells, which the caller invalidates by the inf test.
+
+def _cells_per_dispatch(qp: int, cp: int, d: int) -> int:
+    """Cells of one (qp, cp) bucket pair that one ``_cell_topk`` call
+    takes: the byte budget over one cell's f32 rows and tile, rounded
+    down to a power of two (at least 1).  A function of the bucket pair
+    and d alone, so each pair compiles one program."""
+    g = max(1, _CELL_DISPATCH_BYTES // (4 * ((qp + cp) * d + qp * cp)))
+    return 1 << (g.bit_length() - 1)
+
+
+@functools.partial(jax.jit, static_argnames=("metric", "kk"),
+                   donate_argnames=("part_d", "part_i"))
+def _cell_topk(X, qid, slot, cid, cells, part_d, part_i, *, metric: str,
+               kk: int):
+    """Top-kk candidates per query for a chunk of G (padded) anchor cells,
+    written into the per-(point, probe slot) partial lists.
+
+    The rows come from the device-resident table ``X`` by index: only
+    the int32 id arrays cross from the host.  A loop over the chunk's
+    first ``cells`` cells brute-forces one cell at a time, so the padded
+    cells that fill the chunk up to G are never visited.  Padded
+    candidate columns carry cid = -1 and padded query rows qid = -2
+    (distinct sentinels so padding never self-matches); both gather row
+    0, padded columns mask to +inf before the top_k, so they can only
+    fill trailing slots of undersized cells, which read (inf, -1), and
+    a padded query row writes nothing.  Memory is one cell's rows and
+    tile, O(qp · (d + cp)), plus the O(G · (qp + cp)) ids — nothing
+    (n, n).
+
+    Args:
+      X: (n, d) f32 — the table.
+      qid, slot: (G, qp) int32 — query point ids and the probe slot
+        through which each query reaches its cell.
+      cid: (G, cp) int32 — candidate point ids (the cell's members).
+      cells: int32 scalar — how many of the G cells are real.
+      part_d, part_i: (n, probes, k) f32 / int32 — partial lists,
+        updated in place (donated); a cell fills ``[q, slot, :kk]``.
     """
-    D = pairwise_dissim_ref(Xq, Xc, metric=metric)
-    bad = (cid[None, :] < 0) | (cid[None, :] == qid[:, None])
-    neg, p = jax.lax.top_k(-jnp.where(bad, jnp.inf, D), kk)
-    return -neg, jnp.take(cid, p)
+    n = X.shape[0]
+
+    def one_cell(g, parts):
+        q, c = qid[g], cid[g]
+        D = pairwise_dissim_ref(X[jnp.maximum(q, 0)], X[jnp.maximum(c, 0)],
+                                metric=metric)
+        bad = (c[None, :] < 0) | (c[None, :] == q[:, None])
+        neg, p = jax.lax.top_k(-jnp.where(bad, jnp.inf, D), kk)
+        gd = -neg
+        gi = jnp.where(jnp.isfinite(gd), jnp.take(c, p), -1)
+        r = jnp.where(q >= 0, q, n)          # padded rows fall off the end
+        pd, pi = parts
+        return (pd.at[r, slot[g], :kk].set(gd, mode="drop"),
+                pi.at[r, slot[g], :kk].set(gi, mode="drop"))
+
+    return jax.lax.fori_loop(0, cells, one_cell, (part_d, part_i))
 
 
 def knn_graph_anchored(X, *, k: int, metric: str = "euclidean",
@@ -382,16 +428,25 @@ def knn_graph_anchored(X, *, k: int, metric: str = "euclidean",
     a blocked pass, then brute-force each anchor cell: the candidates
     are the cell's primary members, the queries everyone probing it.
     Probe pools are disjoint (primary assignment partitions the data),
-    so the per-point merge over probes needs no dedup.  Every
-    intermediate is O(assign_block · anchors) or O(cell² ) — nothing
-    (n, n), nothing O(n) per point.
+    so the per-point merge over probes needs no dedup.
+
+    The table stays on the device (a host table is uploaded once).  The
+    host reads the assignment back once and keeps only integer
+    bookkeeping: per cell, padded query and candidate id arrays.  Cells
+    are grouped by their (query, candidate) bucket pair and brute-forced
+    in chunks of ``_cells_per_dispatch`` cells by ``_cell_topk``, which
+    gathers the rows on the device; every chunk is launched before the
+    partial lists are read back once.  Every intermediate is
+    O(assign_block · anchors) or one cell's rows and tile,
+    O(qp · (d + cp)) — nothing (n, n); the partial lists are
+    O(n · probes · k).
 
     Recall is the usual IVF story: a true neighbour is missed only when
     it lives in none of the probed cells; the Borůvka stage's repair
     covers the resulting (rare) disconnections.
 
     Args:
-      X: (n, d) float — data points (numpy in, numpy out; the blocked
+      X: (n, d) float — data points, a host or device array (the blocked
         passes go through ``kernels.ops.pairwise_dist``).
       k: neighbours per point.
       metric: one of ``kernels.ref.METRICS``.
@@ -402,31 +457,29 @@ def knn_graph_anchored(X, *, k: int, metric: str = "euclidean",
       rng: anchor-sampling generator (default_rng(0) when None).
 
     Returns:
-      (dist (n, k) f32, idx (n, k) i64) — ascending per row; slots the
-      probed cells could not fill hold (inf, -1).
+      (dist (n, k) f32, idx (n, k) i64) host arrays — ascending per row;
+      slots the probed cells could not fill hold (inf, -1).
     """
     check_metric(metric)
-    Xn = spans.readback(X, np.float32)
-    n, _ = Xn.shape
+    X = jnp.asarray(X, jnp.float32)
+    n, d = X.shape
     c = anchors if anchors is not None else max(32, int(round(math.sqrt(n))))
     c = min(c, n)
     probes = max(1, min(probes, c))
     rng = rng if rng is not None else np.random.default_rng(0)
     aidx = rng.choice(n, size=c, replace=False)
-    A = jnp.asarray(Xn[aidx])
+    A = X[jnp.asarray(aidx)]
 
-    d = Xn.shape[1]
-    probe_idx = np.empty((n, probes), np.int32)
+    pids = []
     for s0 in range(0, n, assign_block):
-        xb = Xn[s0:s0 + assign_block]
-        rows = xb.shape[0]
-        if rows < assign_block:  # keep one eager shape for the whole pass
-            xb = np.vstack([xb, np.zeros((assign_block - rows, d),
-                                         np.float32)])
-        D = kops.pairwise_dist(jnp.asarray(xb), A, metric=metric,
-                               use_pallas=use_pallas)
-        _, pid = jax.lax.top_k(-D, probes)
-        probe_idx[s0:s0 + rows] = spans.readback(pid, np.int32)[:rows]
+        rows = min(assign_block, n - s0)
+        if rows == assign_block:
+            xb = jax.lax.dynamic_slice_in_dim(X, s0, assign_block)
+        else:  # keep one eager shape for the whole pass
+            xb = jnp.pad(X[s0:], ((0, assign_block - rows), (0, 0)))
+        D = kops.pairwise_dist(xb, A, metric=metric, use_pallas=use_pallas)
+        pids.append(jax.lax.top_k(-D, probes)[1])
+    probe_idx = spans.readback(jnp.concatenate(pids), np.int32)[:n]
 
     # CSR views: candidates by primary cell, queries by each probe slot.
     primary = probe_idx[:, 0]
@@ -439,40 +492,40 @@ def knn_graph_anchored(X, *, k: int, metric: str = "euclidean",
         [[0], np.cumsum(np.bincount(probe_idx[:, s], minlength=c))])
         for s in range(probes)]
 
-    part_d = np.full((n, probes, k), np.inf, np.float32)
-    part_i = np.full((n, probes, k), -1, np.int64)
+    groups: dict[tuple[int, int], list] = {}
     for g in range(c):
         cand = by_cell[start[g]:start[g + 1]]
         if cand.size == 0:
             continue
         qs = [q_order[s][q_start[s][g]:q_start[s][g + 1]]
               for s in range(probes)]
-        slot = np.concatenate(
-            [np.full(x.size, s, np.int64) for s, x in enumerate(qs)])
         q = np.concatenate(qs)
         if q.size == 0:
             continue
-        qp, cp = _bucket(q.size), _bucket(int(cand.size))
-        Xq = np.zeros((qp, d), np.float32)
-        Xq[:q.size] = Xn[q]
-        Xc = np.zeros((cp, d), np.float32)
-        Xc[:cand.size] = Xn[cand]
-        qid = np.full(qp, -2, np.int32)
-        qid[:q.size] = q
-        cid = np.full(cp, -1, np.int32)
-        cid[:cand.size] = cand
-        kk = min(k, cp)
-        gd, gi = _cell_topk(jnp.asarray(Xq), jnp.asarray(Xc),
-                            jnp.asarray(qid), jnp.asarray(cid),
-                            metric=metric, kk=kk)
-        gd = spans.readback(gd, np.float32)[:q.size]
-        gi = spans.readback(gi, np.int64)[:q.size]
-        gi = np.where(np.isfinite(gd), gi, -1)
-        part_d[q, slot, :kk] = gd
-        part_i[q, slot, :kk] = gi
+        slot = np.concatenate(
+            [np.full(x.size, s, np.int32) for s, x in enumerate(qs)])
+        groups.setdefault((_bucket(q.size), _bucket(cand.size)), []).append(
+            (q, slot, cand))
 
-    flat_d = part_d.reshape(n, probes * k)
-    flat_i = part_i.reshape(n, probes * k)
+    part_d = jnp.full((n, probes, k), jnp.inf, jnp.float32)
+    part_i = jnp.full((n, probes, k), -1, jnp.int32)
+    for (qp, cp), group in groups.items():
+        G = _cells_per_dispatch(qp, cp, d)
+        for c0 in range(0, len(group), G):
+            chunk = group[c0:c0 + G]
+            qid = np.full((G, qp), -2, np.int32)
+            slots = np.zeros((G, qp), np.int32)
+            cid = np.full((G, cp), -1, np.int32)
+            for j, (q, slot, cand) in enumerate(chunk):
+                qid[j, :q.size] = q
+                slots[j, :q.size] = slot
+                cid[j, :cand.size] = cand
+            part_d, part_i = _cell_topk(
+                X, qid, slots, cid, np.int32(len(chunk)), part_d, part_i,
+                metric=metric, kk=min(k, cp))
+
+    flat_d = spans.readback(part_d, np.float32).reshape(n, probes * k)
+    flat_i = spans.readback(part_i, np.int64).reshape(n, probes * k)
     sel = np.argsort(flat_d, axis=1, kind="stable")[:, :k]
     return (np.take_along_axis(flat_d, sel, axis=1),
             np.take_along_axis(flat_i, sel, axis=1))
@@ -520,15 +573,18 @@ def approx_vat(X, *, k: int = 15, metric: str = "euclidean",
                                stats)
     k_eff = min(k, n - 1)
     exact = knn_mode == "exact" or (knn_mode == "auto" and n <= EXACT_KNN_N)
+    # the kNN stage works on the device table (a host X is uploaded
+    # once); the host copy serves the Borůvka repair
+    Xd = jnp.asarray(X if isinstance(X, jax.Array) else Xn, jnp.float32)
     with spans.span("approx.knn"):
         if exact:
-            dj, ij = kops.knn_graph(jnp.asarray(Xn), k=k_eff, metric=metric,
+            dj, ij = kops.knn_graph(Xd, k=k_eff, metric=metric,
                                     use_pallas=use_pallas, block=block)
             dist = spans.readback(dj)
             idx = spans.readback(ij, np.int64)
             mode = "exact"
         else:
-            dist, idx = knn_graph_anchored(Xn, k=k_eff, metric=metric,
+            dist, idx = knn_graph_anchored(Xd, k=k_eff, metric=metric,
                                            anchors=anchors, probes=probes,
                                            use_pallas=use_pallas, rng=rng)
             mode = "anchored"

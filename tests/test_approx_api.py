@@ -4,6 +4,8 @@ error report riding on ``ResultMeta``, and the memory story — a dispatch
 census pinning the kNN kernel to Pallas calls plus the no-(n,n) tripwire
 mirror from test_bigvat.py."""
 import dataclasses
+import functools
+from collections import Counter
 
 import numpy as np
 import jax
@@ -13,8 +15,10 @@ import pytest
 from repro import core
 from repro.api import FastVAT, registry as reg, select_method
 from repro.api.registry import MEDIUM_N
+from repro.core import approx_mst
 from repro.core.approx_mst import ApproxStats, knn_graph_anchored
 from repro.kernels import ops as kops
+from repro.kernels.ref import pairwise_dissim_ref
 
 
 def _blobs(n, k=3, d=2, seed=0, sep=40.0):
@@ -233,3 +237,163 @@ def test_approx_demo_acceptance(monkeypatch):
     assert info["stats"].k == 6
     assert all(r * c <= n * 64 for r, c in shapes), shapes
     assert info["working_bytes"] * 20 < info["dense_bytes"]
+
+
+# ----------------------------------- anchored kNN: the cell loop ----
+
+@functools.partial(jax.jit, static_argnames=("metric", "kk"))
+def _one_cell_topk(Xq, Xc, qid, cid, *, metric, kk):
+    """One (padded) anchor cell's top-kk, from host-gathered rows: the
+    per-cell program the batched ``_cell_topk`` replaced."""
+    D = pairwise_dissim_ref(Xq, Xc, metric=metric)
+    bad = (cid[None, :] < 0) | (cid[None, :] == qid[:, None])
+    neg, p = jax.lax.top_k(-jnp.where(bad, jnp.inf, D), kk)
+    return -neg, jnp.take(cid, p)
+
+
+def _anchored_reference(X, *, k, metric, probes, assign_block):
+    """The anchored kNN as a host loop over cells: host blocks for the
+    assignment, then per cell a host gather of its rows, one
+    ``_one_cell_topk`` call and a read.  Returns (dist, idx) and the
+    counts of cells without candidates and of cells with no queries."""
+    Xn = np.asarray(X, np.float32)
+    n, d = Xn.shape
+    c = max(32, int(round(np.sqrt(n))))
+    aidx = np.random.default_rng(0).choice(n, size=c, replace=False)
+    A = jnp.asarray(Xn[aidx])
+    probe_idx = np.empty((n, probes), np.int32)
+    for s0 in range(0, n, assign_block):
+        xb = Xn[s0:s0 + assign_block]
+        rows = xb.shape[0]
+        if rows < assign_block:
+            xb = np.vstack([xb, np.zeros((assign_block - rows, d),
+                                         np.float32)])
+        D = kops.pairwise_dist(jnp.asarray(xb), A, metric=metric)
+        _, pid = jax.lax.top_k(-D, probes)
+        probe_idx[s0:s0 + rows] = np.asarray(pid, np.int32)[:rows]
+
+    primary = probe_idx[:, 0]
+    by_cell = np.argsort(primary, kind="stable")
+    start = np.concatenate([[0],
+                            np.cumsum(np.bincount(primary, minlength=c))])
+    q_order = [np.argsort(probe_idx[:, s], kind="stable")
+               for s in range(probes)]
+    q_start = [np.concatenate(
+        [[0], np.cumsum(np.bincount(probe_idx[:, s], minlength=c))])
+        for s in range(probes)]
+
+    part_d = np.full((n, probes, k), np.inf, np.float32)
+    part_i = np.full((n, probes, k), -1, np.int64)
+    no_cand = no_query = 0
+    for g in range(c):
+        cand = by_cell[start[g]:start[g + 1]]
+        qs = [q_order[s][q_start[s][g]:q_start[s][g + 1]]
+              for s in range(probes)]
+        slot = np.concatenate(
+            [np.full(x.size, s, np.int64) for s, x in enumerate(qs)])
+        q = np.concatenate(qs)
+        no_cand += cand.size == 0
+        no_query += q.size == 0
+        if cand.size == 0 or q.size == 0:
+            continue
+        qp, cp = approx_mst._bucket(q.size), approx_mst._bucket(cand.size)
+        Xq = np.zeros((qp, d), np.float32)
+        Xq[:q.size] = Xn[q]
+        Xc = np.zeros((cp, d), np.float32)
+        Xc[:cand.size] = Xn[cand]
+        qid = np.full(qp, -2, np.int32)
+        qid[:q.size] = q
+        cid = np.full(cp, -1, np.int32)
+        cid[:cand.size] = cand
+        kk = min(k, cp)
+        gd, gi = _one_cell_topk(jnp.asarray(Xq), jnp.asarray(Xc),
+                                jnp.asarray(qid), jnp.asarray(cid),
+                                metric=metric, kk=kk)
+        gd = np.asarray(gd, np.float32)[:q.size]
+        gi = np.asarray(gi, np.int64)[:q.size]
+        part_d[q, slot, :kk] = gd
+        part_i[q, slot, :kk] = np.where(np.isfinite(gd), gi, -1)
+
+    flat_d = part_d.reshape(n, probes * k)
+    flat_i = part_i.reshape(n, probes * k)
+    sel = np.argsort(flat_d, axis=1, kind="stable")[:, :k]
+    return (np.take_along_axis(flat_d, sel, axis=1),
+            np.take_along_axis(flat_i, sel, axis=1), no_cand, no_query)
+
+
+def _with_twins(n, seed):
+    """Blobs whose first quarter is one repeated point: the anchors drawn
+    among the copies tie, and all but the first get no primary member."""
+    X, _ = _blobs(n, k=3, d=5, seed=seed, sep=8.0)
+    X[:n // 4] = X[0]
+    return X
+
+
+@pytest.mark.parametrize("on_device", [False, True], ids=["host", "device"])
+@pytest.mark.parametrize("probes", [1, 2, 3])
+@pytest.mark.parametrize("metric",
+                         ["euclidean", "sqeuclidean", "cosine", "manhattan"])
+def test_anchored_cells_on_device_equal_the_per_cell_loop(
+        monkeypatch, metric, probes, on_device):
+    """The batched, device-resident cell stage returns the host loop's
+    (dist, idx) bit for bit.  A small dispatch budget makes several
+    chunks per bucket pair and a padded last chunk; the repeated point
+    makes cells with no candidates (and, at one probe, no queries)."""
+    n, k, ab = 1_200, 6, 512
+    X = _with_twins(n, seed=10 + probes)
+    want_d, want_i, no_cand, no_query = _anchored_reference(
+        X, k=k, metric=metric, probes=probes, assign_block=ab)
+    assert no_cand > 0
+    assert no_query > 0 if probes == 1 else no_cand > no_query
+
+    chunks = Counter()
+    real = approx_mst._cell_topk
+
+    def recording(X, qid, slot, cid, cells, *args, **kw):
+        chunks[(qid.shape[1], cid.shape[1])] += 1
+        padded.append(int(cells) < qid.shape[0])
+        return real(X, qid, slot, cid, cells, *args, **kw)
+
+    padded = []
+    monkeypatch.setattr(approx_mst, "_cell_topk", recording)
+    monkeypatch.setattr(approx_mst, "_CELL_DISPATCH_BYTES", 48 << 10)
+    got_d, got_i = knn_graph_anchored(jnp.asarray(X) if on_device else X,
+                                      k=k, metric=metric, probes=probes,
+                                      assign_block=ab)
+    assert len(chunks) > 1 and max(chunks.values()) > 1 and any(padded)
+    assert got_d.dtype == np.float32 and got_i.dtype == np.int64
+    np.testing.assert_array_equal(got_d, want_d)
+    np.testing.assert_array_equal(got_i, want_i)
+
+
+def test_anchored_cells_compile_once_per_bucket_pair(monkeypatch):
+    """One ``_cell_topk`` program per (query, candidate) bucket pair over
+    three tables, and a second fit of the same table compiles and traces
+    nothing (what the benchmark's window checks)."""
+    pairs = set()
+    real = approx_mst._cell_topk
+
+    def recording(X, qid, slot, cid, *args, **kw):
+        pairs.add((qid.shape[1], cid.shape[1]))
+        return real(X, qid, slot, cid, *args, **kw)
+
+    monkeypatch.setattr(approx_mst, "_cell_topk", recording)
+    real.clear_cache()
+    tables = [jnp.asarray(_blobs(2_000, k=4, d=6, seed=s)[0])
+              for s in (21, 22, 23)]
+    for X in tables:
+        core.approx_vat(X, k=8, knn_mode="anchored")
+    assert 0 < real._cache_size() <= len(pairs)
+
+    events = []
+
+    def on_event(event, duration, **kw):
+        if event.startswith("/jax/core/compile/"):
+            events.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        core.approx_vat(tables[-1], k=8, knn_mode="anchored")
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert events == []

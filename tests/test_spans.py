@@ -167,9 +167,11 @@ def test_anchored_approx_records_its_stages_and_every_blocking_read(
                     for rb in recs if rb.name == "device.readback")
     st = res.stats
     repair = 1 if 1 < st.components <= approx_mst.REPAIR_MAX_C else 0
-    # the table, one per assignment block, two per cell top-k call
+    # the table; then the assignment, read once for all its blocks, and
+    # the two partial lists, read once after every cell call was launched
     assert reads["caller"] == 1
-    assert reads["approx.knn"] == 1 + 2 * calls["cell"]
+    assert calls["cell"] >= 1
+    assert reads["approx.knn"] == 1 + 2
     # four per Borůvka pass that recorded edges, the last pass's flag,
     # the final labels, and the repair's distances when it ran
     assert reads["approx.boruvka"] == 4 * st.n_passes + 2 + repair
